@@ -14,7 +14,7 @@ import jax
 import numpy as np
 import jax.numpy as jnp
 
-from repro.backend import registry
+from repro.backend import probe, registry
 from repro.core import band_reduce, chase_sequential, chase_wavefront
 from benchmarks.common import bench, emit, is_smoke
 
@@ -51,14 +51,14 @@ def run():
         from repro.kernels.ops import bulge_uses_kernel
 
         kernel = registry.resolve("bulge_chase", "pallas")
-        ran_kernel = bulge_uses_kernel(n)  # same decision bulge_chase makes
+        ran_kernel = bulge_uses_kernel(n, b, group=1)  # bulge_chase's decision
         t_pal = bench(jax.jit(lambda M, b=b, kernel=kernel: kernel(M, b)), B)
         emit(
             f"bulge_pallas_n{n}_b{b}", t_pal,
             f"path={'kernel' if ran_kernel else 'xla_fallback'};"
             + (
-                f"interpret={'off' if registry.probe.is_tpu() else 'on'};"
-                f"vmem_resident={int(registry.probe.is_tpu())}"
+                f"interpret={'off' if probe.is_tpu() else 'on'};"
+                f"vmem_resident={int(probe.is_tpu())}"
                 if ran_kernel else "above_interpret_ceiling=1"
             ),
             op="bulge_chase", n=n, backend="pallas",

@@ -38,5 +38,5 @@ def run(n: int = 256):
                 f"{kind.lower()}_n{n}_b{b}_nb{nb}", t_br,
                 f"bulge_chase_us={t_bc*1e6:.1f};total_us={(t_br+t_bc)*1e6:.1f};"
                 f"update_k={nb}",
-                op="band_reduce", n=n, backend=registry.effective_default_backend(),
+                op="band_reduce", n=n, backend=registry.default_backend(),
             )

@@ -52,4 +52,4 @@ def run():
         t = bench(step, params, state, batch, jnp.zeros((), jnp.int32))
         emit(f"train_step_{name}", t, f"arch={cfg.name};smoke=1",
              op="train_step", n=cfg.d_model,
-             backend=registry.effective_default_backend())
+             backend=registry.default_backend())

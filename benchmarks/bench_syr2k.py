@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.backend import registry
+from repro.backend import probe, registry
 from benchmarks.common import bench, emit, is_smoke
 
 
@@ -39,7 +39,7 @@ def run():
             fn = registry.resolve("syr2k", backend)
             t = bench(jax.jit(lambda a, b, c, fn=fn: fn(a, b, c)), A, B, C)
             extra = (
-                f";interpret={'off' if registry.probe.is_tpu() else 'on'}"
+                f";interpret={'off' if probe.is_tpu() else 'on'}"
                 f";tile_flop_savings=0.5" if backend == "pallas" else ""
             )
             emit(

@@ -55,6 +55,9 @@ def main() -> None:
     )
     from benchmarks import common
     from repro.backend import probe, registry
+    from repro.backend.cache import enable_compilation_cache
+
+    enable_compilation_cache()
 
     suites = {
         "syr2k": bench_syr2k.run,

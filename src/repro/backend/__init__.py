@@ -3,16 +3,16 @@
 This is the architectural seam between the algorithm layer (``repro.core``)
 and the kernel layer (``repro.kernels``):
 
-* ``repro.backend.compat``   — the ONE place that papers over jax API drift
-  (``shard_map`` location, ``TPUCompilerParams`` naming, ``make_mesh``
-  axis types).
-* ``repro.backend.probe``    — platform / interpret-mode / Pallas capability.
+* ``repro.backend.compat``   — ``make_mesh`` with ``Auto`` axis types.
+* ``repro.backend.probe``    — platform / interpret-mode.
+* ``repro.backend.cache``    — the persistent compilation cache directory
+  (set up by scripts at start-up, never on import).
 * ``repro.backend.registry`` — hot-op -> kernel dispatch with per-backend
   tile defaults and the ``REPRO_KERNEL_BACKEND`` override.
 """
 from . import compat, probe, registry
-from .compat import shard_map, make_mesh, tpu_compiler_params
-from .probe import platform, interpret_mode, pallas_available
+from .compat import make_mesh
+from .probe import platform, interpret_mode
 from .registry import (
     resolve,
     register,
@@ -26,12 +26,9 @@ __all__ = [
     "compat",
     "probe",
     "registry",
-    "shard_map",
     "make_mesh",
-    "tpu_compiler_params",
     "platform",
     "interpret_mode",
-    "pallas_available",
     "resolve",
     "register",
     "default_backend",

@@ -1,123 +1,20 @@
-"""The ONE place that papers over JAX API drift.
+"""Mesh construction with the axis types the solver's ``shard_map`` paths use.
 
-Everything in the framework that touches a JAX symbol whose home or spelling
-has moved between releases imports it from here, so a jax upgrade is a
-one-file change:
-
-* ``shard_map`` — ``jax.shard_map`` on new jax, ``jax.experimental.shard_map``
-  on jax <= 0.4.x; the replication-check kwarg is ``check_vma`` on new jax
-  and ``check_rep`` before the rename.  :func:`shard_map` accepts
-  ``check_vma`` and translates.
-* TPU Pallas compiler params — ``pltpu.CompilerParams`` on new jax,
-  ``pltpu.TPUCompilerParams`` before the rename.  Dimension semantics are
-  passed as the portable string literals ``"parallel"`` / ``"arbitrary"``.
-* ``jax.make_mesh`` — the ``axis_types`` kwarg (and ``jax.sharding.AxisType``
-  itself) only exists on new jax; :func:`make_mesh` requests Auto axes when
-  the running jax supports them and silently omits them otherwise.
+``jax.make_mesh`` defaults to ``Explicit`` axes; every mesh in this
+framework is consumed by ``jax.shard_map`` with manual in/out specs, which
+wants ``Auto`` axes.  :func:`make_mesh` is ``jax.make_mesh`` with that
+default flipped; pass ``axis_types=...`` to override it.
 """
 from __future__ import annotations
 
-import inspect
-from typing import Any, Optional, Sequence
+from typing import Sequence
 
 import jax
 
-__all__ = [
-    "shard_map",
-    "make_mesh",
-    "tpu_compiler_params",
-    "cost_analysis",
-    "PARALLEL",
-    "ARBITRARY",
-]
-
-# Portable dimension-semantics spellings (both old TPUCompilerParams and new
-# CompilerParams accept the string literals).
-PARALLEL = "parallel"
-ARBITRARY = "arbitrary"
-
-
-# --------------------------------------------------------------- shard_map
-try:  # new jax: top-level export
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_SHARD_MAP_PARAMS = inspect.signature(_shard_map).parameters
-_REP_KWARG = "check_vma" if "check_vma" in _SHARD_MAP_PARAMS else "check_rep"
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: Optional[bool] = None, **kw):
-    """Version-tolerant ``shard_map``.
-
-    ``check_vma`` is the new-jax name for the replication check; it is mapped
-    to ``check_rep`` on older jax.  All other kwargs pass through.
-    """
-    if check_vma is not None:
-        kw[_REP_KWARG] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
-# --------------------------------------------------------------- make_mesh
-_MAKE_MESH = getattr(jax, "make_mesh", None)  # absent before jax 0.4.35
-_MAKE_MESH_PARAMS = (
-    inspect.signature(_MAKE_MESH).parameters if _MAKE_MESH is not None else {}
-)
+__all__ = ["make_mesh"]
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], **kw):
-    """``jax.make_mesh`` with Auto axis types where the running jax has them.
-
-    Callers never touch ``jax.sharding.AxisType`` directly (absent on jax
-    <= 0.4.x); pass ``axis_types=...`` only to override the Auto default.
-    On jax builds predating ``jax.make_mesh`` the mesh is assembled from
-    ``mesh_utils.create_device_mesh`` directly.
-    """
-    shape = tuple(axis_shapes)
-    names = tuple(axis_names)
-    if _MAKE_MESH is None:
-        from jax.experimental import mesh_utils
-
-        kw.pop("axis_types", None)
-        devices = kw.pop("devices", None)
-        return jax.sharding.Mesh(
-            mesh_utils.create_device_mesh(shape, devices=devices), names
-        )
-    if "axis_types" in _MAKE_MESH_PARAMS:
-        if "axis_types" not in kw:
-            axis_type = getattr(jax.sharding, "AxisType", None)
-            if axis_type is not None:
-                kw["axis_types"] = (axis_type.Auto,) * len(names)
-    else:
-        kw.pop("axis_types", None)
-    return _MAKE_MESH(shape, names, **kw)
-
-
-# ------------------------------------------------------------ cost_analysis
-def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalized across jax versions.
-
-    Newer jax returns one dict; jax <= 0.4.x returns a per-device list of
-    dicts.  Returns a (possibly empty) dict either way.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
-
-
-# ------------------------------------------------- TPU Pallas compiler params
-def tpu_compiler_params(
-    *, dimension_semantics: Optional[Sequence[str]] = None, **kw: Any
-):
-    """Construct TPU Pallas compiler params under either spelling.
-
-    ``dimension_semantics`` entries are the string literals
-    :data:`PARALLEL` / :data:`ARBITRARY`.
-    """
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    if dimension_semantics is not None:
-        kw["dimension_semantics"] = tuple(dimension_semantics)
-    return cls(**kw)
+    """``jax.make_mesh`` with ``AxisType.Auto`` on every axis by default."""
+    kw.setdefault("axis_types", (jax.sharding.AxisType.Auto,) * len(axis_names))
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kw)

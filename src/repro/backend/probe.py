@@ -1,22 +1,17 @@
 """Capability probe: what accelerator substrate is this process running on?
 
-The answers drive kernel dispatch (``repro.backend.registry``):
+The answers drive kernel dispatch (``repro.kernels.ops``):
 
 * :func:`platform` — the active XLA backend ("cpu" | "tpu" | "gpu").
 * :func:`interpret_mode` — whether Pallas kernels must run under the
-  interpreter (anywhere that is not a real TPU; the brief's validation mode).
-* :func:`pallas_available` — whether the Pallas TPU lowering machinery can
-  even be imported (old jax builds, CPU-only wheels without the TPU plugin
-  still ship the interpreter, so this is almost always True — but the
-  registry degrades to the jnp reference backend when it is not).
+  interpreter (anywhere that is not a real TPU; the validation mode).  On a
+  TPU no kernel is interpreted unless its caller passes ``interpret=True``.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 
-__all__ = ["platform", "is_tpu", "interpret_mode", "pallas_available"]
+__all__ = ["platform", "is_tpu", "interpret_mode"]
 
 
 def platform() -> str:
@@ -31,19 +26,3 @@ def is_tpu() -> bool:
 def interpret_mode() -> bool:
     """Pallas interpret mode: on for CPU/GPU (validation), off on real TPUs."""
     return not is_tpu()
-
-
-@functools.lru_cache(maxsize=None)
-def pallas_available() -> bool:
-    """Can Pallas kernels be built in this process (compiled or interpreted)?"""
-    try:
-        import jax.experimental.pallas  # noqa: F401
-        from jax.experimental.pallas import tpu as pltpu
-
-        # Either compiler-params spelling must exist for the TPU kernels.
-        return (
-            getattr(pltpu, "CompilerParams", None) is not None
-            or getattr(pltpu, "TPUCompilerParams", None) is not None
-        )
-    except Exception:  # pragma: no cover - exotic/broken installs
-        return False

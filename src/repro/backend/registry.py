@@ -29,17 +29,15 @@ Each op maps to one of two backends:
   as the numerical-parity oracle for the Pallas path.
 
 Resolution order: programmatic override (:func:`set_backend` /
-:func:`use_backend`) > ``REPRO_KERNEL_BACKEND`` env var > ``"pallas"``
-whenever Pallas is importable.  Future backends (GPU pallas, pure-XLA
-variants, distributed) plug in via :func:`register`.
+:func:`use_backend`) > ``REPRO_KERNEL_BACKEND`` env var > ``"pallas"``.
+Future backends (GPU pallas, pure-XLA variants, distributed) plug in via
+:func:`register`.
 """
 from __future__ import annotations
 
 import os
 from contextlib import contextmanager
 from typing import Callable, Dict, Optional, Tuple
-
-from . import probe
 
 __all__ = [
     "ENV_VAR",
@@ -48,7 +46,6 @@ __all__ = [
     "OPS",
     "TRIDIAGS",
     "default_backend",
-    "effective_default_backend",
     "default_tridiag",
     "set_backend",
     "use_backend",
@@ -107,20 +104,7 @@ def default_backend() -> str:
     env = os.environ.get(ENV_VAR)
     if env:
         return _validate(env)
-    return "pallas" if probe.pallas_available() else "jnp"
-
-
-def effective_default_backend() -> str:
-    """The default backend after graceful degradation: a pallas default on a
-    platform without Pallas falls back to the always-available jnp reference
-    path.  (An EXPLICIT backend request never degrades — parity tests would
-    compare the oracle against itself.)  The one home of this policy, shared
-    by :func:`resolve` and ``repro.solver.plan``.
-    """
-    be = default_backend()
-    if be == "pallas" and not probe.pallas_available():
-        return "jnp"
-    return be
+    return "pallas"
 
 
 def default_tridiag() -> str:
@@ -207,30 +191,29 @@ def _build_impls() -> None:
     default("panel_qr", "jnp", panel_qr_geqrf)
     default("backtransform_wy", "jnp", backtransform_wy_xla)
 
-    if probe.pallas_available():
-        from repro.kernels import ops as kops
+    from repro.kernels import ops as kops
 
-        def pallas_trailing_update(C, Y, Z):
-            return kops.trailing_update(C, Y, Z, **tile_defaults("trailing_update"))
+    def pallas_trailing_update(C, Y, Z):
+        return kops.trailing_update(C, Y, Z, **tile_defaults("trailing_update"))
 
-        def pallas_syr2k(A, B, C=None, *, alpha: float = 1.0):
-            return kops.syr2k(A, B, C, alpha=alpha, **tile_defaults("syr2k"))
+    def pallas_syr2k(A, B, C=None, *, alpha: float = 1.0):
+        return kops.syr2k(A, B, C, alpha=alpha, **tile_defaults("syr2k"))
 
-        def pallas_fused_panel_update(Bv, b, w):
-            return kops.fused_panel_update(
-                Bv, b, w, **tile_defaults("fused_panel_update")
-            )
+    def pallas_fused_panel_update(Bv, b, w):
+        return kops.fused_panel_update(
+            Bv, b, w, **tile_defaults("fused_panel_update")
+        )
 
-        def pallas_bulge_wavefront(B, b, *, return_log=False):
-            return kops.bulge_wavefront(B, b, return_log=return_log)
+    def pallas_bulge_wavefront(B, b, *, return_log=False):
+        return kops.bulge_wavefront(B, b, return_log=return_log)
 
-        default("trailing_update", "pallas", pallas_trailing_update)
-        default("syr2k", "pallas", pallas_syr2k)
-        default("fused_panel_update", "pallas", pallas_fused_panel_update)
-        default("bulge_chase", "pallas", kops.bulge_chase)
-        default("bulge_wavefront", "pallas", pallas_bulge_wavefront)
-        default("panel_qr", "pallas", kops.panel_qr)
-        default("backtransform_wy", "pallas", kops.backtransform_wy)
+    default("trailing_update", "pallas", pallas_trailing_update)
+    default("syr2k", "pallas", pallas_syr2k)
+    default("fused_panel_update", "pallas", pallas_fused_panel_update)
+    default("bulge_chase", "pallas", kops.bulge_chase)
+    default("bulge_wavefront", "pallas", pallas_bulge_wavefront)
+    default("panel_qr", "pallas", kops.panel_qr)
+    default("backtransform_wy", "pallas", kops.backtransform_wy)
 
     # Only mark built on success: a failed import above propagates, stays
     # unbuilt, and is retried (surfacing the real error) on the next resolve.
@@ -245,12 +228,7 @@ def resolve(op: str, backend: Optional[str] = None) -> Callable:
     """
     if op not in OPS:
         raise KeyError(f"unknown op {op!r}; expected one of {OPS}")
-    if backend is None:
-        be = effective_default_backend()
-    else:
-        # An explicit backend request must not be silently downgraded —
-        # parity tests would compare the oracle against itself.
-        be = _validate(backend)
+    be = default_backend() if backend is None else _validate(backend)
     if not _built:
         _build_impls()
     impl = _IMPLS.get((op, be))

@@ -65,19 +65,26 @@ __all__ = [
 def _merge_block_ts(Vg: jax.Array, Ts: jax.Array, b: int) -> jax.Array:
     """Fuse q per-panel T factors into one (q·b, q·b) block-reflector T.
 
-    Vg: (n, q·b) — the block's panels side by side; Ts: (q, b, b).
+    Vg: (n, q·b) — the block's panels side by side; Ts: (q, b, b).  One
+    ``lax.scan`` over panels with static shapes: at step j the rows and
+    columns of Tm from j·b on are still zero, so the full-width products
+    equal the prefix products of the recurrence.
     """
     q = Ts.shape[0]
     w = q * b
     Tm = jnp.zeros((w, w), Vg.dtype)
     Tm = Tm.at[:b, :b].set(Ts[0])
-    for j in range(1, q):
+
+    def merge(Tm, j):
         c0 = j * b
-        Vpre = Vg[:, :c0]
-        Vj = Vg[:, c0 : c0 + b]
-        cross = -Tm[:c0, :c0] @ ((Vpre.T @ Vj) @ Ts[j])
-        Tm = Tm.at[:c0, c0 : c0 + b].set(cross)
-        Tm = Tm.at[c0 : c0 + b, c0 : c0 + b].set(Ts[j])
+        Vj = lax.dynamic_slice_in_dim(Vg, c0, b, axis=1)
+        Tj = Ts[j]
+        cross = -Tm @ ((Vg.T @ Vj) @ Tj)  # rows from c0 on are zero
+        Tm = lax.dynamic_update_slice_in_dim(Tm, cross, c0, axis=1)
+        Tm = lax.dynamic_update_slice(Tm, Tj, (c0, c0))
+        return Tm, None
+
+    Tm, _ = lax.scan(merge, Tm, jnp.arange(1, q))
     return Tm
 
 

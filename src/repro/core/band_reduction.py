@@ -16,11 +16,12 @@ This module implements the paper's stage-1 algorithms:
 Inside a block we use LAPACK-``latrd``-style *compensation* instead of
 physically updating panel columns: panel j's columns and its `A @ V` product
 are corrected against the accumulated (V, Z) of panels < j with a single
-GEMM pair of k = j·b.  This is the same FLOP-reaggregation idea as the
-paper's recursive panel-update schedule (§5.1) — both exist to make the
-intra-block updates large GEMMs instead of many skinny ones — expressed in
-the form that maps best onto XLA/TPU (one growing-k GEMM instead of a
-recursion tree of launches).  See DESIGN.md §2.
+GEMM pair of k = w (over buffers whose later columns are still zero).  This
+is the same FLOP-reaggregation idea as the paper's recursive panel-update
+schedule (§5.1) — both exist to make the intra-block updates large GEMMs
+instead of many skinny ones — expressed in the form that maps best onto
+XLA/TPU (one k = w GEMM instead of a recursion tree of launches).  See
+DESIGN.md §2.
 
 Shapes are static per block (Python loop over blocks with shrinking trailing
 views), so everything jits and vmaps.  The trailing update and panel
@@ -37,6 +38,7 @@ from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.backend import registry
 
@@ -167,49 +169,51 @@ def _reduce_block(
     """Reduce the first ``w`` columns of the trailing view ``Bv`` (m, m) to
     bandwidth ``b`` and apply one rank-2w trailing update.
 
+    The q = w/b panels run as one ``lax.scan`` with static shapes, so a block
+    compiles once however many panels it has: the factor buffers are full
+    width (columns of panels not yet factored are zero, so full-width GEMMs
+    equal the prefix GEMMs), and panel j's rows [r0, m) are rotated to the
+    top of a zero-padded (m, b) panel for the QR.
+
     Returns (new_view, Vbuf (m, w), Ts (w//b, b, b)).
     """
     m = Bv.shape[0]
     q = w // b
     dtype = Bv.dtype
+    rows = jnp.arange(m)[:, None]
+    cols = jnp.arange(b)[None, :]
 
-    Vbuf = jnp.zeros((m, w), dtype)
-    Zbuf = jnp.zeros((m, w), dtype)
-    F = jnp.zeros((m, w), dtype)  # exact final values of the factored columns
-    Ts = []
-
-    for j in range(q):
+    def panel(carry, j):
+        Vbuf, Zbuf, F = carry
         c0 = j * b
         r0 = c0 + b  # elimination starts below this row
         # --- compensated panel: P = (B - Z V^T - V Z^T)[:, c0:c0+b] --------
-        P = Bv[:, c0 : c0 + b]
-        if j > 0:
-            Vpre = Vbuf[:, :c0]
-            Zpre = Zbuf[:, :c0]
-            P = P - Zpre @ Vbuf[c0 : c0 + b, :c0].T - Vpre @ Zbuf[c0 : c0 + b, :c0].T
+        P = lax.dynamic_slice_in_dim(Bv, c0, b, axis=1)
+        Vrow = lax.dynamic_slice_in_dim(Vbuf, c0, b, axis=0)
+        Zrow = lax.dynamic_slice_in_dim(Zbuf, c0, b, axis=0)
+        P = P - Zbuf @ Vrow.T - Vbuf @ Zrow.T
         # --- panel QR of rows [r0, m) ---------------------------------------
-        V_j, T_j, _taus, R_j = panel_qr_fn(P[r0:, :])
-        Vhat = jnp.zeros((m, b), dtype).at[r0:, :].set(V_j)
+        # Zero rows below the rotated panel leave V, T and R unchanged.
+        low = jnp.roll(jnp.where(rows >= r0, P, 0.0), -r0, axis=0)
+        V_j, T_j, _taus, R_j = panel_qr_fn(low)
+        Vhat = jnp.roll(V_j, r0, axis=0)
         # --- exact final column values (band structure) ---------------------
-        zeros_tail = jnp.zeros((m - r0, b), dtype)
-        R_embed = zeros_tail.at[:b, :].set(R_j[:b, :]) if (m - r0) >= b else R_j[: m - r0, :]
-        fcol = jnp.concatenate([P[:r0, :], R_embed], axis=0)
+        R_embed = lax.dynamic_update_slice(jnp.zeros((m, b), dtype), R_j, (r0, 0))
+        fcol = jnp.where(rows < r0, P, R_embed)
         # Structurally-banded write-back: entries above the band are exact
         # zeros in exact arithmetic; mask out their rounding fuzz.
-        col_global = c0 + jnp.arange(b)[None, :]
-        in_band = jnp.arange(m)[:, None] >= col_global - b
-        F = F.at[:, c0 : c0 + b].set(jnp.where(in_band, fcol, 0.0))
+        in_band = rows >= c0 + cols - b
+        F = lax.dynamic_update_slice_in_dim(F, jnp.where(in_band, fcol, 0.0), c0, axis=1)
         # --- Z_j = A_cur Vhat T  - 1/2 Vhat T^T (Vhat^T A_cur Vhat) T --------
-        M = Bv @ Vhat
-        if j > 0:
-            M = M - Zbuf[:, :c0] @ (Vbuf[:, :c0].T @ Vhat) - Vbuf[:, :c0] @ (
-                Zbuf[:, :c0].T @ Vhat
-            )
+        M = Bv @ Vhat - Zbuf @ (Vbuf.T @ Vhat) - Vbuf @ (Zbuf.T @ Vhat)
         MT = M @ T_j
         Z_j = MT - 0.5 * Vhat @ (T_j.T @ (Vhat.T @ MT))
-        Vbuf = Vbuf.at[:, c0 : c0 + b].set(Vhat)
-        Zbuf = Zbuf.at[:, c0 : c0 + b].set(Z_j)
-        Ts.append(T_j)
+        Vbuf = lax.dynamic_update_slice_in_dim(Vbuf, Vhat, c0, axis=1)
+        Zbuf = lax.dynamic_update_slice_in_dim(Zbuf, Z_j, c0, axis=1)
+        return (Vbuf, Zbuf, F), T_j
+
+    zeros = jnp.zeros((m, w), dtype)
+    (Vbuf, Zbuf, F), Ts = lax.scan(panel, (zeros, zeros, zeros), jnp.arange(q))
 
     # --- one rank-2w trailing update with k = w (the paper's big syr2k) -----
     trailing = syr2k_update(Bv[w:, w:], Vbuf[w:, :], Zbuf[w:, :])
@@ -217,7 +221,7 @@ def _reduce_block(
     new_view = new_view.at[w:, w:].set(trailing)
     new_view = new_view.at[:, :w].set(F)
     new_view = new_view.at[:w, w:].set(F[w:, :].T)
-    return new_view, Vbuf, jnp.stack(Ts)
+    return new_view, Vbuf, Ts
 
 
 def band_reduce(

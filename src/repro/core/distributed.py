@@ -29,7 +29,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.backend import registry
-from repro.backend.compat import shard_map
 from repro.solver import EvdConfig, solve_many
 
 __all__ = [
@@ -58,7 +57,7 @@ def dist_trailing_update(
         z_blk = jax.lax.dynamic_slice_in_dim(z_full, idx * rows, rows, 0)
         return a_blk - z_blk @ y_full.T - y_blk @ z_full.T
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axis, None), P(None, None), P(None, None)),
@@ -76,7 +75,7 @@ def dist_symm_matmul(mesh: Mesh, axis: str, A: jax.Array, V: jax.Array) -> jax.A
         m_blk = a_blk @ v_full  # (n/d, k)
         return jax.lax.all_gather(m_blk, axis, axis=0, tiled=True)
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axis, None), P(None, None)),

@@ -1,74 +1,71 @@
-"""Interpret-mode and VMEM residency ceilings for ALL Pallas kernels.
+"""VMEM accounting and interpret-mode ceilings for ALL Pallas kernels.
 
-Every VMEM-resident kernel in this package has two dispatch ceilings:
+Every VMEM-resident kernel in this package dispatches on two numbers:
 
-* a **VMEM ceiling** — the largest problem whose resident working set fits
-  a ~16 MB fp32 TPU core; above it the jit wrappers in ``repro.kernels.ops``
-  fall back to the XLA implementation (same math, HBM-resident).
+* its **VMEM bytes** — the bytes the ``pallas_call`` really holds in VMEM:
+  every block in its (8, 128)-tiled layout, times its buffer count (2 for
+  pipelined blocks, 1 for resident blocks declared ``pl.Buffered(1)``),
+  plus its scratch.  Each kernel module exports the function that counts
+  them (``*_vmem_bytes``), built from the same shapes as its BlockSpecs.
+  The ops wrappers in ``repro.kernels.ops`` run the kernel only when
+  :func:`fits_vmem` accepts that count, and the kernel passes
+  :func:`vmem_limit_bytes` of the same count to the compiler — so dispatch
+  and compiler agree by construction.
 * an **interpret ceiling** — off-TPU the kernels run under the Pallas
   interpreter for validation only, and the emulated grid unrolls into the
   traced program; above the validation sizes the wrappers fall back so CPU
   oracle runs stay cheap.  An EXPLICIT ``interpret=True`` (validating the
   kernel itself) bypasses the interpret ceiling — see the ops wrappers.
 
-This module is the ONE home for those numbers (they used to be scattered:
-the bulge ceiling as an ops-module constant sometimes overridden via a
-test env var, the back-transform ceiling inline in its kernel module).
-Every ceiling can be overridden with an environment variable
+The budget is a quarter of the 128 MiB of VMEM a TPU v5e core has.  At
+b = 8, nb = 256 it admits the fused panel kernel on trailing views up to
+m = 1280 (m = 1536 counts 36 MiB), and the bulge and Q2 back-transform
+kernels with vectors up to n ~ 1900.  It is twice the compiler's default
+scoped limit (16 MiB on a v5e), so every kernel passes its own limit: the
+count plus :data:`VMEM_HEADROOM_BYTES` for Mosaic's internal scratch
+(:func:`vmem_limit_bytes`).
+
+Every entry of :data:`LIMITS` can be overridden with an environment variable
 ``REPRO_<NAME>`` (e.g. ``REPRO_BULGE_INTERPRET_MAX_N=128``) — read at call
-time, so tests and deployments can retune dispatch without code changes.
+time, so tests can retune dispatch without code changes.
 
-Ceilings (fp32 elements unless named ``_N``/``_M``, which are matrix sides):
-
-==============================  =======  ==========================================
-name                            default  gates
-==============================  =======  ==========================================
-BULGE_VMEM_MAX_N                   1408  bulge wavefront kernel (padded matrix
-                                         resident: ~(n + 6b)^2 * 4 bytes)
-BULGE_INTERPRET_MAX_N                64  same kernel off-TPU (3(n-3)+1 grid steps
-                                         unroll under the interpreter)
-BACKTRANSFORM_VMEM_MAX_ELEMS    4194304  blocked Q2 back-transform (two resident
-                                         (n + K*b, m) panels + reflector block)
-BACKTRANSFORM_INTERPRET_MAX_N        48  same kernel off-TPU ((S,)-grid emulation)
-FUSED_PANEL_VMEM_MAX_ELEMS      3145728  fused panel+trailing kernel (resident
-                                         trailing view + V/Z/F factor buffers)
-FUSED_PANEL_INTERPRET_MAX_M          96  same kernel off-TPU (the in-kernel panel
-                                         recurrence unrolls q*b column steps)
-PANEL_QR_VMEM_MAX_M                8192  fused panel-QR kernel (panel + ~3
-                                         temporaries resident; b <= 64)
-==============================  =======  ==========================================
+==============================  ========  =====================================
+name                            default   gates
+==============================  ========  =====================================
+VMEM_BUDGET_BYTES               32 MiB    every compiled kernel (counted bytes)
+BULGE_INTERPRET_MAX_N                 64  bulge kernel off-TPU (3(n-3)+1 grid
+                                          steps unroll under the interpreter)
+BACKTRANSFORM_INTERPRET_MAX_N         48  Q2 back-transform off-TPU ((S,)-grid)
+FUSED_PANEL_INTERPRET_MAX_M           96  fused panel kernel off-TPU
+==============================  ========  =====================================
 """
 from __future__ import annotations
 
+import math
 import os
 
-__all__ = ["LIMITS", "ENV_PREFIX", "limit"]
+__all__ = [
+    "LIMITS",
+    "ENV_PREFIX",
+    "limit",
+    "tile_bytes",
+    "fits_vmem",
+    "vmem_limit_bytes",
+]
 
 ENV_PREFIX = "REPRO_"
 
+# Physical VMEM of one TPU v5e TensorCore.
+VMEM_CAPACITY_BYTES = 128 * 1024 * 1024
+# Mosaic's internal scratch and spilled temporaries, on top of the counted
+# blocks.
+VMEM_HEADROOM_BYTES = 8 * 1024 * 1024
+
 LIMITS = {
-    # fp32 VMEM ceiling for the VMEM-resident bulge kernel (kernels/bulge.py).
-    "BULGE_VMEM_MAX_N": 1408,
-    # Off-TPU the kernel exists for validation only (no VMEM to be resident
-    # in) and the emulated grid unrolls all 3(n-3)+1 wavefronts into the
-    # traced program — above validation sizes fall back to the XLA executor.
+    "VMEM_BUDGET_BYTES": 32 * 1024 * 1024,
     "BULGE_INTERPRET_MAX_N": 64,
-    # VMEM budget for the resident back-transform panels (+ streamed
-    # reflector block), in fp32 elements (~16 MB core).  BOTH the input and
-    # output (n + K*b, m) padded panels are constant-index blocks (resident),
-    # so the gate counts two copies (kernels/backtransform.py).
-    "BACKTRANSFORM_VMEM_MAX_ELEMS": 4 * 1024 * 1024,
-    # Off-TPU the emulated (S,)-grid costs one interpreter step per sweep.
     "BACKTRANSFORM_INTERPRET_MAX_N": 48,
-    # VMEM budget for the fused panel+trailing kernel, in fp32 elements: the
-    # whole (m, m) trailing view is resident plus four (m, w) factor buffers
-    # (V, Z, F and the streamed output tile) — see kernels/fused_panel.py.
-    "FUSED_PANEL_VMEM_MAX_ELEMS": 3 * 1024 * 1024,
-    # Off-TPU the in-kernel panel recurrence unrolls q*b Householder column
-    # steps per block; validation sizes only (m = trailing-view side).
     "FUSED_PANEL_INTERPRET_MAX_M": 96,
-    # Panel m*b*4 bytes + ~3 temporaries must fit VMEM (kernels/panel.py).
-    "PANEL_QR_VMEM_MAX_M": 8192,
 }
 
 
@@ -86,3 +83,22 @@ def limit(name: str) -> int:
     if env is not None and env != "":
         return int(env)
     return LIMITS[name]
+
+
+def tile_bytes(shape, itemsize: int = 4, buffers: int = 1) -> int:
+    """VMEM bytes of one block of ``shape``: the last two dims pad to the
+    (8, 128) tile, leading dims multiply."""
+    *lead, r, c = (1,) * max(0, 2 - len(shape)) + tuple(shape)
+    rows = -(-r // 8) * 8
+    cols = -(-c // 128) * 128
+    return buffers * math.prod(lead) * rows * cols * itemsize
+
+
+def fits_vmem(nbytes: int) -> bool:
+    """Whether a kernel holding ``nbytes`` of VMEM blocks may dispatch."""
+    return nbytes <= limit("VMEM_BUDGET_BYTES")
+
+
+def vmem_limit_bytes(nbytes: int) -> int:
+    """The compiler's scoped-VMEM limit for a kernel holding ``nbytes``."""
+    return min(nbytes + VMEM_HEADROOM_BYTES, VMEM_CAPACITY_BYTES)

@@ -22,12 +22,12 @@ import jax.numpy as jnp
 
 from repro.backend import probe
 
-from .limits import limit
+from .limits import fits_vmem, limit
 from .syr2k import syr2k_lower_pallas
-from .bulge import bulge_wavefront_pallas
+from .bulge import bulge_vmem_bytes, bulge_wavefront_pallas
 from .panel import panel_qr_pallas
-from .fused_panel import fused_panel_update_pallas
-from .backtransform import backtransform_wy_pallas
+from .fused_panel import fused_panel_update_pallas, fused_tpu_aligned, fused_vmem_bytes
+from .backtransform import backtransform_wy_pallas, column_block
 
 __all__ = [
     "syr2k",
@@ -42,8 +42,9 @@ __all__ = [
     "backtransform_uses_kernel",
 ]
 
-# All interpret-mode / VMEM dispatch ceilings live in repro.kernels.limits
-# (one table, env-overridable); the wrappers below read them at call time.
+# The VMEM budget and the interpret-mode ceilings live in repro.kernels.limits;
+# each kernel module counts its own VMEM bytes.  The *_uses_kernel functions
+# below are the single source of truth for kernel-versus-XLA dispatch.
 
 
 def _pad_to(x: jax.Array, mult0: int, mult1: int) -> jax.Array:
@@ -107,13 +108,9 @@ def fused_uses_kernel(
     interp = probe.interpret_mode() if interpret is None else interpret
     if interp and not explicit:
         return m <= limit("FUSED_PANEL_INTERPRET_MAX_M")
-    mt = m - w
-    bm = min(bm, max(8, 1 << (mt - 1).bit_length()))
-    mt_pad = -(-mt // bm) * bm
-    m_pad = w + mt_pad
-    # Resident trailing view + V/F/Z factor buffers + the streamed out tile.
-    resident = m_pad * m_pad + 3 * m_pad * w + bm * bm
-    return resident <= limit("FUSED_PANEL_VMEM_MAX_ELEMS")
+    if not interp and not fused_tpu_aligned(m, w, b, bm):
+        return False
+    return fits_vmem(fused_vmem_bytes(m, w, b, bm))
 
 
 def fused_panel_update(
@@ -151,16 +148,33 @@ def fused_panel_update(
     return new_view, V[:m], Ts
 
 
-def bulge_uses_kernel(n: int, *, interpret: Optional[bool] = None) -> bool:
+def bulge_uses_kernel(
+    n: int,
+    b: int,
+    *,
+    group: Optional[int] = None,
+    return_log: bool = False,
+    interpret: Optional[bool] = None,
+) -> bool:
     """Whether :func:`bulge_chase` / :func:`bulge_wavefront` at size ``n``
     run the Pallas kernel (True) or the XLA wavefront fallback (False).
     Single source of truth for the dispatch decision — benchmarks and
     diagnostics must use this rather than re-deriving the ceilings.
     """
+    if n < 3 or b <= 1:
+        return False
     explicit = interpret is not None
     interp = probe.interpret_mode() if interpret is None else interpret
-    name = "BULGE_INTERPRET_MAX_N" if (interp and not explicit) else "BULGE_VMEM_MAX_N"
-    return n <= limit(name)
+    if interp and not explicit:
+        return n <= limit("BULGE_INTERPRET_MAX_N")
+    group = _wavefront_group(n, b) if group is None else group
+    return fits_vmem(bulge_vmem_bytes(n, b, group=group, return_log=return_log))
+
+
+def _wavefront_group(n: int, b: int) -> int:
+    from repro.solver.autotune import wavefront_group
+
+    return wavefront_group(n, b)
 
 
 def bulge_chase(B: jax.Array, b: int, *, interpret: Optional[bool] = None) -> jax.Array:
@@ -171,7 +185,7 @@ def bulge_chase(B: jax.Array, b: int, *, interpret: Optional[bool] = None) -> ja
     the platform; an EXPLICIT ``interpret=True`` (validation of the kernel
     itself) runs the kernel up to the VMEM ceiling regardless of cost.
     """
-    if not bulge_uses_kernel(B.shape[0], interpret=interpret):
+    if not bulge_uses_kernel(B.shape[0], b, group=1, interpret=interpret):
         from repro.core.bulge_chasing import chase_wavefront
 
         return chase_wavefront(B, b)
@@ -198,13 +212,12 @@ def bulge_wavefront(
     n = B.shape[0]
     from repro.core.bulge_chasing import ChaseLog, chase_wavefront_slices
 
-    if n < 3 or b <= 1 or not bulge_uses_kernel(n, interpret=interpret):
+    group = _wavefront_group(n, b) if (group is None and n >= 3) else group
+    if not bulge_uses_kernel(
+        n, b, group=group, return_log=return_log, interpret=interpret
+    ):
         return chase_wavefront_slices(B, b, return_log)
     interpret = probe.interpret_mode() if interpret is None else interpret
-    if group is None:
-        from repro.solver.autotune import wavefront_group
-
-        group = wavefront_group(n, b)
     if not return_log:
         return bulge_wavefront_pallas(B, b, group=int(group), interpret=interpret)
     out, (vs, taus, row0) = bulge_wavefront_pallas(
@@ -220,22 +233,33 @@ def panel_qr(panel: jax.Array, *, interpret: Optional[bool] = None):
 
 
 def backtransform_uses_kernel(
-    n: int, m: int, b: int, *, interpret: Optional[bool] = None
+    n: int,
+    m: int,
+    b: int,
+    *,
+    group: Optional[int] = None,
+    interpret: Optional[bool] = None,
 ) -> bool:
     """Whether the blocked back-transform at panel shape (n, m) runs the
     Pallas kernel (True) or the XLA scan fallback (False).  Single source of
     truth for the dispatch decision, like :func:`bulge_uses_kernel`.
+    ``group`` None means one group per sweep, as in :func:`backtransform_wy`.
     """
     explicit = interpret is not None
     interp = probe.interpret_mode() if interpret is None else interpret
     if interp and not explicit:
         return n <= limit("BACKTRANSFORM_INTERPRET_MAX_N")
+    return _bt_column_block(n, m, b, group) > 0
+
+
+def _bt_column_block(n: int, m: int, b: int, group: Optional[int]) -> int:
     from repro.core.backtransform import _sweep_shape
 
     S, K = _sweep_shape(n, b)
-    # Two resident padded panels (in + out) + one streamed reflector block.
-    resident = 2 * (n + K * b) * m + K * b
-    return S > 0 and resident <= limit("BACKTRANSFORM_VMEM_MAX_ELEMS")
+    if S == 0:
+        return 0
+    group = K if group is None else group
+    return column_block(n, m, K, b, group, fits_vmem)
 
 
 def backtransform_wy(
@@ -256,7 +280,7 @@ def backtransform_wy(
     size ceiling.
     """
     n, m = X.shape
-    if not backtransform_uses_kernel(n, m, b, interpret=interpret):
+    if not backtransform_uses_kernel(n, m, b, group=group, interpret=interpret):
         from repro.core.backtransform import backtransform_wy_xla
 
         return backtransform_wy_xla(
@@ -266,6 +290,6 @@ def backtransform_wy(
     K = vs.shape[1]
     group = K if group is None else group
     return backtransform_wy_pallas(
-        X, vs, taus, b=b, group=int(group), transpose=transpose,
-        interpret=interpret,
+        X, vs, taus, b=b, group=int(group), mb=_bt_column_block(n, m, b, group),
+        transpose=transpose, interpret=interpret,
     )

@@ -30,9 +30,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.backend.compat import tpu_compiler_params, PARALLEL, ARBITRARY
+from .limits import tile_bytes, vmem_limit_bytes
 
-__all__ = ["syr2k_lower_pallas", "lower_tile_indices"]
+__all__ = ["syr2k_lower_pallas", "lower_tile_indices", "syr2k_vmem_bytes"]
 
 
 def lower_tile_indices(n_tiles: int) -> tuple[np.ndarray, np.ndarray]:
@@ -47,6 +47,14 @@ def lower_tile_indices(n_tiles: int) -> tuple[np.ndarray, np.ndarray]:
             ii.append(i)
             jj.append(j)
     return np.asarray(ii, np.int32), np.asarray(jj, np.int32)
+
+
+def syr2k_vmem_bytes(bm: int, bk: int, itemsize: int = 4) -> int:
+    """VMEM bytes held by :func:`syr2k_lower_pallas`: four (bm, bk) operand
+    strips and the C input and output tiles, all double-buffered."""
+    return tile_bytes((bm, bk), itemsize, buffers=2) * 4 + tile_bytes(
+        (bm, bm), itemsize, buffers=2
+    ) * 2
 
 
 def _syr2k_kernel(i_ref, j_ref, a_i, b_j, b_i, a_j, c_in, c_out, *, alpha, nk):
@@ -120,8 +128,11 @@ def syr2k_lower_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, n), C.dtype),
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=(PARALLEL, ARBITRARY),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes(
+                syr2k_vmem_bytes(bm, bk, jnp.dtype(C.dtype).itemsize)
+            ),
         ),
         interpret=interpret,
         name="syr2k_lower",

@@ -193,10 +193,8 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool = False, overrides=None,
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
 
-    from repro.backend.compat import cost_analysis
-
     ma = compiled.memory_analysis()
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     hlo = compiled.as_text()
     colls = collective_bytes_from_hlo(hlo)
     walk = analyze_hlo(hlo, top=12)

@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.backend.compat import shard_map
-
 __all__ = ["quantize_int8", "dequantize_int8", "compressed_psum", "ef_compress_transform"]
 
 
@@ -51,7 +49,7 @@ def compressed_psum(mesh: Mesh, axis: str, x: jax.Array) -> jax.Array:
         n = jax.lax.psum(jnp.ones((), jnp.float32), axis)
         return (total.astype(jnp.float32) * (s_tot / n)) / n
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(),),

@@ -30,7 +30,7 @@ bit-identical to a per-matrix ``EvdPlan`` loop on the jnp reference
 backend (rounding-level on the Pallas default: interpreted kernels fuse
 with surrounding ops, so vmap can perturb rounding).
 
-``devices=`` routes every bucket through the compat ``shard_map`` path
+``devices=`` routes every bucket through the ``jax.shard_map`` path
 (batch sharded over the mesh, full solver local per device) — this is the
 engine under ``repro.core.distributed.sharded_eigh_batch`` /
 ``sharded_inverse_roots``, which are now thin deprecated shims.
@@ -45,10 +45,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.backend.compat import shard_map
-
 from .batch import PadPolicy, batch_plan
 from .config import EvdConfig, Spectrum
+from .plan import MATMUL_PRECISION
 
 __all__ = ["solve_many"]
 
@@ -105,7 +104,8 @@ def _roots_from_window(w, V, p: int, eps: float):
     ridge = jnp.asarray(eps, w.dtype) * jnp.maximum(wmax, 1e-30)
     w_safe = jnp.maximum(w, 0.0) + ridge[:, None]
     root = jnp.power(w_safe, -1.0 / p)
-    return jnp.einsum("bik,bk,bjk->bij", V, root, V)
+    with jax.default_matmul_precision(MATMUL_PRECISION):
+        return jnp.einsum("bik,bk,bjk->bij", V, root, V)
 
 
 def _pad_batch(stack: jax.Array, target: int) -> jax.Array:
@@ -158,7 +158,7 @@ def _run_bucket(
             local, out_specs = (
                 lambda a: bpl.inverse_pth_root(a, p, eps=eps)
             ), spec_m
-        out = shard_map(
+        out = jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(spec_m,),

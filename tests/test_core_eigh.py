@@ -70,6 +70,39 @@ def test_inverse_iteration_residuals(rng):
     np.testing.assert_allclose(np.asarray(V).T @ np.asarray(V), np.eye(n), atol=1e-4)
 
 
+def _clustered(rng, n, kind):
+    if kind == "low_rank":  # Shampoo statistics: rank n/4 plus a ridge
+        G = rng.normal(size=(n, n // 4))
+        A = G @ G.T / (n // 4)
+        return A + 0.1 * np.trace(A) / n * np.eye(n)
+    # xLATMS style: geometric magnitudes plus an n/4 cluster of width 1e-6
+    Q, _ = np.linalg.qr(rng.normal(size=(n, n)))
+    k = 3 * n // 4
+    lam = np.concatenate([
+        1e-4 ** (np.arange(k) / (k - 1)) * rng.choice([-1.0, 1.0], size=k),
+        0.5 * (1.0 + 1e-6 * rng.uniform(size=n - k)),
+    ])
+    return (Q * lam) @ Q.T
+
+
+@pytest.mark.parametrize("kind", ["low_rank", "geometric_cluster"])
+def test_inverse_iteration_clustered_spectra(rng, kind):
+    # Lanes shifted onto single members of a cluster used to converge onto
+    # parallel vectors, leaving residuals ~1e4 n*eps after the final QR.
+    n = 128
+    T = sla.hessenberg(_clustered(rng, n, kind))
+    d = np.diag(T).astype(np.float32)
+    e = np.diag(T, 1).astype(np.float32)
+    w = eigvalsh_tridiag(jnp.asarray(d), jnp.asarray(e))
+    V = np.asarray(eigvecs_inverse_iteration(jnp.asarray(d), jnp.asarray(e), w), np.float64)
+    T32 = np.diag(d) + np.diag(e, 1) + np.diag(e, -1)
+    w = np.asarray(w, np.float64)
+    bound = n * np.finfo(np.float32).eps
+    resid = np.linalg.norm(T32 @ V - V * w[None, :], axis=0).max() / np.abs(w).max()
+    assert resid <= bound
+    assert np.abs(V.T @ V - np.eye(n)).max() <= bound
+
+
 # ---------------------------------------------------------------- full eigh
 @pytest.mark.parametrize(
     "method,kw",

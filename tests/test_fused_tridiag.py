@@ -255,3 +255,43 @@ def test_mode_validation_errors(rng):
     # Injected phases own the composition: fused mode must refuse them.
     with pytest.raises(ValueError):
         band_reduce(A, 4, 8, mode="fused", panel_method="householder")
+
+
+# ------------------------------------------------------------- VMEM budget
+def test_tile_bytes_pads_to_vreg_tiles():
+    from repro.kernels.limits import tile_bytes
+
+    assert tile_bytes((1, 8)) == 8 * 128 * 4
+    assert tile_bytes((3, 10, 130), buffers=2) == 2 * 3 * 16 * 256 * 4
+    assert tile_bytes((256,)) == 8 * 256 * 4
+
+
+@pytest.mark.parametrize(
+    "m,w,fused",
+    [
+        (1280, 256, True),   # the largest trailing view inside the budget
+        (1536, 256, False),  # over the VMEM budget: unfused composition
+        (256, 248, False),   # w not lane-aligned on the TPU: unfused
+    ],
+)
+def test_fused_dispatch_counts_vmem_bytes(m, w, fused):
+    from repro.kernels.fused_panel import fused_vmem_bytes
+    from repro.kernels.limits import fits_vmem, limit, vmem_limit_bytes
+
+    assert kops.fused_uses_kernel(m, w, 8, bm=128, interpret=False) is fused
+    nbytes = fused_vmem_bytes(m, w, 8, 128)
+    # The compiler is given room for every counted byte.
+    assert vmem_limit_bytes(nbytes) > nbytes
+    if w % 128 == 0:
+        assert fits_vmem(nbytes) is fused
+        assert (nbytes <= limit("VMEM_BUDGET_BYTES")) is fused
+
+
+def test_q2_and_bulge_dispatch_count_vmem_bytes():
+    # The Q2 back-transform tiles columns, so a 4096 panel stays on the
+    # kernel; the bulge kernel keeps the whole padded matrix and does not.
+    assert kops.backtransform_uses_kernel(1024, 1024, 8, group=16, interpret=False)
+    assert kops.backtransform_uses_kernel(4096, 4096, 8, group=16, interpret=False)
+    assert kops._bt_column_block(4096, 4096, 8, 16) == 512
+    assert kops.bulge_uses_kernel(1024, 8, return_log=True, interpret=False)
+    assert not kops.bulge_uses_kernel(4096, 8, interpret=False)

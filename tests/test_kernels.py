@@ -84,7 +84,6 @@ def test_bulge_kernel_vs_sequential(rng, n, b):
 def test_bulge_kernel_large_falls_back(monkeypatch, rng):
     import repro.kernels.ops as ops
 
-    monkeypatch.setenv("REPRO_BULGE_VMEM_MAX_N", "8")
     monkeypatch.setenv("REPRO_BULGE_INTERPRET_MAX_N", "8")
     n, b = 16, 4
     B = band_reduce(jnp.asarray(random_symmetric(rng, n)), b, b)
