@@ -112,24 +112,13 @@ def kernels_in(hlo: str) -> list:
 
 
 def expected_kernels(n: int, config, eigenvectors: bool) -> set:
-    """The kernels ``repro.kernels.ops`` dispatches for one (n, n) solve."""
+    """The kernels one (n, n) solve dispatches, as its plan records them
+    (``EvdPlan.paths``, decided by ``repro.kernels.ops``)."""
     import jax.numpy as jnp
 
-    from repro.core.band_reduction import build_stage_schedule
-    from repro.kernels import ops
-    from repro.solver import plan, tile_defaults
+    from repro.solver import plan
 
-    pl = plan(n, jnp.float32, config)
-    bm = tile_defaults("fused_panel_update")["bm"]
-    want = set()
-    for e in build_stage_schedule(n, pl.b, pl.nb).entries:
-        fused = ops.fused_uses_kernel(e.m, e.w, pl.b, bm=bm)
-        want.add("fused_panel_update" if fused else "syr2k_lower")
-    if ops.bulge_uses_kernel(n, pl.b, return_log=eigenvectors):
-        want.add("bulge_chase_wavefront")
-    if eigenvectors and ops.backtransform_uses_kernel(n, pl.k, pl.b, group=pl.bt_group):
-        want.add("backtransform_wy")
-    return want
+    return set(plan(n, jnp.float32, config).kernels(eigenvectors))
 
 
 class Phase:

@@ -15,6 +15,8 @@ The EVD pipeline has three hot ops (the paper's Table 1 decomposition):
 * ``panel_qr``        — the WY-form panel factorization.
 * ``backtransform_wy`` — the blocked compact-WY eigenvector back-transform
   (sweep-major grouped Q2 application; see ``repro.core.backtransform``).
+* ``stage_mark``      — a named no-op kernel marking a stage boundary on the
+  device timeline (``repro.kernels.mark``); the identity under ``jnp``.
 
 This module also owns the process-level ``tridiag`` pipeline default
 (:func:`default_tridiag`): ``REPRO_TRIDIAG=fused|unfused`` mirrors
@@ -66,6 +68,7 @@ OPS = (
     "bulge_wavefront",
     "panel_qr",
     "backtransform_wy",
+    "stage_mark",
 )
 TRIDIAGS = ("fused", "unfused")
 
@@ -190,6 +193,7 @@ def _build_impls() -> None:
     default("bulge_wavefront", "jnp", jnp_bulge_wavefront)
     default("panel_qr", "jnp", panel_qr_geqrf)
     default("backtransform_wy", "jnp", backtransform_wy_xla)
+    default("stage_mark", "jnp", lambda tile, stage: tile)
 
     from repro.kernels import ops as kops
 
@@ -214,6 +218,7 @@ def _build_impls() -> None:
     default("bulge_wavefront", "pallas", pallas_bulge_wavefront)
     default("panel_qr", "pallas", kops.panel_qr)
     default("backtransform_wy", "pallas", kops.backtransform_wy)
+    default("stage_mark", "pallas", kops.stage_mark)
 
     # Only mark built on success: a failed import above propagates, stays
     # unbuilt, and is retried (surfacing the real error) on the next resolve.

@@ -8,6 +8,8 @@
   panel         — fused Householder panel QR in WY form (paper §5.1)
   backtransform — VMEM-resident blocked compact-WY eigenvector
                   back-transform (DESIGN.md §6)
+  mark          — ``evd_mark_<stage>``: a named no-op that marks a stage
+                  boundary of a solve on the device timeline
 
 The framework resolves these through ``repro.backend.registry`` (which also
 owns the interpret-mode decision and tile defaults); oracles live in

@@ -28,6 +28,7 @@ from .bulge import bulge_vmem_bytes, bulge_wavefront_pallas
 from .panel import panel_qr_pallas
 from .fused_panel import fused_panel_update_pallas, fused_tpu_aligned, fused_vmem_bytes
 from .backtransform import backtransform_wy_pallas, column_block
+from .mark import stage_mark_pallas
 
 __all__ = [
     "syr2k",
@@ -40,6 +41,7 @@ __all__ = [
     "panel_qr",
     "backtransform_wy",
     "backtransform_uses_kernel",
+    "stage_mark",
 ]
 
 # The VMEM budget and the interpret-mode ceilings live in repro.kernels.limits;
@@ -293,3 +295,10 @@ def backtransform_wy(
         X, vs, taus, b=b, group=int(group), mb=_bt_column_block(n, m, b, group),
         transpose=transpose, interpret=interpret,
     )
+
+
+def stage_mark(tile: jax.Array, stage: str, *, interpret: Optional[bool] = None) -> jax.Array:
+    """``tile`` (8, 128) unchanged, through the kernel ``evd_mark_<stage>``:
+    a mark of the stage boundary on the device timeline."""
+    interpret = probe.interpret_mode() if interpret is None else interpret
+    return stage_mark_pallas(tile, stage, interpret=interpret)

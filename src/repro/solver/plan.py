@@ -22,6 +22,7 @@ module.  Imports of the pipeline stages are deferred (``_deps``) so that
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from collections import Counter
@@ -30,14 +31,16 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.backend import probe, registry
 
-from .autotune import backtransform_group, resolve_blocking
+from .autotune import backtransform_group, resolve_blocking, tile_defaults
 from .config import EvdConfig, Spectrum
 
 __all__ = [
     "EvdPlan",
+    "StagePath",
     "plan",
     "plan_for",
     "clear_plan_cache",
@@ -89,6 +92,26 @@ _deps = _Deps()
 
 
 # ------------------------------------------------------------------ pipeline
+def _end_stage(stage: str, outs):
+    """Mark the end of ``stage`` on the device timeline; ``outs`` unchanged.
+
+    ``outs`` pass through an optimization barrier, a ``stage_mark`` kernel
+    (``evd_mark_<stage>``) takes a tile cut from them, and ``outs`` and the
+    mark pass through a second barrier: the mark runs after every operation
+    that produces ``outs`` and before any operation that consumes them.
+    Under the ``jnp`` registry backend the mark is the identity and no
+    kernel is emitted.
+    """
+    from repro.kernels.mark import MARK_TILE
+
+    outs = lax.optimization_barrier(outs)
+    first = jax.tree_util.tree_leaves(outs)[0]   # never empty: n, k >= 1
+    tile = jnp.broadcast_to(first.reshape(-1)[0].astype(jnp.float32), MARK_TILE)
+    marked = registry.resolve("stage_mark")(tile, stage)
+    outs, _ = lax.optimization_barrier((outs, marked))
+    return outs
+
+
 def _tridiag_pipeline(
     A, *, b, nb, method, chase, return_reflectors=False, merge_reflectors=False,
     tridiag=None,
@@ -99,6 +122,10 @@ def _tridiag_pipeline(
     None = process default); both generations emit identical
     ``BandReflectors``/``ChaseLog`` structures, so everything downstream
     (bisection, inverse iteration, back-transform) is mode-oblivious.
+
+    The two-stage method runs its stages under ``jax.named_scope``
+    ``evd.first_stage`` and ``evd.bulge_chase`` and marks the end of each
+    (:func:`_end_stage`); the direct method is one stage of its caller's.
     """
     if method == "direct":
         T, refl = _deps.direct_tridiagonalize(A, return_reflectors=True)
@@ -110,40 +137,56 @@ def _tridiag_pipeline(
     if not return_reflectors:
         # Values-only fast path: no reflector log, so the bulge chase can
         # dispatch to the VMEM-resident Pallas kernel via the registry.
-        Bband = _deps.band_reduce(A, b, nb, mode=tridiag)
-        T = _deps.band_to_tridiag(Bband, b, method=chase, mode=tridiag)
-        return _deps.extract_tridiag(T)
+        with jax.named_scope("evd.first_stage"):
+            Bband = _deps.band_reduce(A, b, nb, mode=tridiag)
+        Bband = _end_stage("first_stage", Bband)
+        with jax.named_scope("evd.bulge_chase"):
+            T = _deps.band_to_tridiag(Bband, b, method=chase, mode=tridiag)
+            d, e = _deps.extract_tridiag(T)
+        return _end_stage("bulge_chase", (d, e))
 
-    Bband, refl1 = _deps.band_reduce(
-        A, b, nb, return_reflectors=True, merge_ts=merge_reflectors, mode=tridiag
-    )
-    T, log2 = _deps.band_to_tridiag(
-        Bband, b, method=chase, return_log=True, mode=tridiag
-    )
-    d, e = _deps.extract_tridiag(T)
+    with jax.named_scope("evd.first_stage"):
+        Bband, refl1 = _deps.band_reduce(
+            A, b, nb, return_reflectors=True, merge_ts=merge_reflectors, mode=tridiag
+        )
+    Bband, refl1 = _end_stage("first_stage", (Bband, refl1))
+    with jax.named_scope("evd.bulge_chase"):
+        T, log2 = _deps.band_to_tridiag(
+            Bband, b, method=chase, return_log=True, mode=tridiag
+        )
+        d, e = _deps.extract_tridiag(T)
+    d, e, refl1, log2 = _end_stage("bulge_chase", (d, e, refl1, log2))
     return d, e, ("two_stage", (refl1, log2))
 
 
-def _backtransform(
-    kind_refl, X: jax.Array, *, mode: str = "scan", group: int = 0
-) -> jax.Array:
+def _backtransform(kind_refl, X: jax.Array, carry=(), *, mode: str = "scan", group: int = 0):
     """x_A = Q x_T where Q is the accumulated tridiagonalization transform.
 
     ``mode`` selects the eigenvector back-transform path: ``blocked`` runs
     the compact-WY GEMM subsystem (``repro.core.backtransform`` — Q2 through
     the registry ``backtransform_wy`` op with WY group size ``group``, Q1
     through the per-block T-merged appliers); ``scan`` runs the per-reflector
-    oracle appliers.
+    oracle appliers.  The two-stage Q2 and Q1 run under ``jax.named_scope``
+    ``evd.backtransform_q2`` / ``evd.backtransform_q1``, each end marked;
+    ``carry`` passes through the marks with ``X``, so that nothing that
+    reads it runs before the last.  Returns ``(X, carry)``.
     """
     kind, refl = kind_refl
     if kind == "direct":
-        return _deps.apply_q_direct(refl, X, transpose=False)
+        return _deps.apply_q_direct(refl, X, transpose=False), carry
     refl1, log2 = refl
-    if mode == "blocked":
-        X = _deps.apply_q2_blocked(log2, X, transpose=False, group=group or None)
-        return _deps.apply_q_left_blocked(refl1, X, transpose=False)
-    X = _deps.apply_q2(log2, X, transpose=False)        # Q2 @ X
-    return _deps.apply_q_left(refl1, X, transpose=False)  # Q1 @ (Q2 @ X)
+    with jax.named_scope("evd.backtransform_q2"):
+        if mode == "blocked":
+            X = _deps.apply_q2_blocked(log2, X, transpose=False, group=group or None)
+        else:
+            X = _deps.apply_q2(log2, X, transpose=False)        # Q2 @ X
+    X, refl1, carry = _end_stage("backtransform_q2", (X, refl1, carry))
+    with jax.named_scope("evd.backtransform_q1"):
+        if mode == "blocked":
+            X = _deps.apply_q_left_blocked(refl1, X, transpose=False)
+        else:
+            X = _deps.apply_q_left(refl1, X, transpose=False)   # Q1 @ (Q2 @ X)
+    return _end_stage("backtransform_q1", (X, carry))
 
 
 def tridiagonalize(
@@ -177,6 +220,66 @@ def tridiagonalize(
 
 
 # ------------------------------------------------------------------ the plan
+XLA = "xla"
+
+
+@dataclasses.dataclass(frozen=True)
+class StagePath:
+    """Where one stage of a solve runs, as ``repro.kernels.ops.*_uses_kernel``
+    decide it when the plan is made: ``kernel`` names the Pallas kernel, or
+    is ``"xla"`` for the XLA path.  ``widths`` are the first stage's trailing
+    widths m that take it; ``eigenvectors`` None holds for values-only and
+    eigenvector solves alike, True or False for one of them."""
+
+    stage: str
+    kernel: str
+    widths: Tuple[int, ...] = ()
+    eigenvectors: Optional[bool] = None
+
+    def describe(self) -> str:
+        out = self.kernel
+        if self.widths:
+            out += " m=" + ",".join(map(str, self.widths))
+        if self.eigenvectors is not None:
+            out += " (vectors)" if self.eigenvectors else " (values)"
+        return out
+
+
+def _stage_paths(n, b, nb, k, *, config, tridiag, platform, bt_group):
+    """The kernel-versus-XLA record of a two-stage plan on the Pallas backend."""
+    from repro.core.backtransform import _sweep_shape
+    from repro.core.band_reduction import build_stage_schedule
+    from repro.kernels import ops
+
+    bm = tile_defaults("fused_panel_update", platform)["bm"]
+    first: Dict[str, list] = {}
+    for e in build_stage_schedule(n, b, nb).entries:
+        fused = tridiag == "fused" and ops.fused_uses_kernel(e.m, e.w, b, bm=bm)
+        first.setdefault("fused_panel_update" if fused else "syr2k_lower", []).append(e.m)
+    paths = [StagePath("first_stage", kern, tuple(ms)) for kern, ms in first.items()]
+
+    for vectors in (False, True):
+        if config.chase != "wavefront" or (tridiag == "unfused" and vectors):
+            kernel = False
+        elif tridiag == "unfused":
+            kernel = ops.bulge_uses_kernel(n, b, group=1)
+        else:
+            kernel = ops.bulge_uses_kernel(n, b, return_log=vectors)
+        paths.append(StagePath(
+            "bulge_chase", "bulge_chase_wavefront" if kernel else XLA, eigenvectors=vectors
+        ))
+
+    kernel = (
+        config.backtransform == "blocked"
+        and 0 not in _sweep_shape(n, b)
+        and ops.backtransform_uses_kernel(n, k, b, group=bt_group or None)
+    )
+    paths.append(StagePath(
+        "backtransform_q2", "backtransform_wy" if kernel else XLA, eigenvectors=True
+    ))
+    return tuple(paths)
+
+
 @dataclasses.dataclass(frozen=True)
 class EvdPlan:
     """A fully-resolved, cached, executable EVD solver for one (n, dtype).
@@ -198,6 +301,8 @@ class EvdPlan:
     bt_group: int = 0                # blocked back-transform WY group size G
                                      # (0: back-transform not applicable)
     tridiag: str = "fused"           # resolved first-stage pipeline generation
+    paths: Tuple[StagePath, ...] = ()  # kernel-versus-XLA record (Pallas
+                                       # backend, two-stage method only)
 
     # ---- derived views ----------------------------------------------------
     @property
@@ -262,7 +367,19 @@ class EvdPlan:
         ]
         if self.fallback_reason:
             parts.append(f"  fallback: {self.fallback_reason}")
+        stages: Dict[str, list] = {}
+        for p in self.paths:
+            stages.setdefault(p.stage, []).append(p.describe())
+        parts += [f"  {stage}: " + "; ".join(ds) for stage, ds in stages.items()]
         return "\n".join(parts)
+
+    def kernels(self, eigenvectors: bool) -> frozenset:
+        """Names of the Pallas kernels of the stages a solve dispatches to
+        (values only, or with ``eigenvectors``), read from :attr:`paths`."""
+        return frozenset(
+            p.kernel for p in self.paths
+            if p.kernel != XLA and p.eigenvectors in (None, eigenvectors)
+        )
 
 
 # ------------------------------------------------------------------ planning
@@ -307,6 +424,12 @@ def plan(n: int, dtype, config: EvdConfig = EvdConfig()) -> EvdPlan:
     bt_group = 0
     if config.method == "two_stage" and b > 1 and config.backtransform == "blocked":
         bt_group = backtransform_group(n, b, platform)
+    paths = ()
+    if config.method == "two_stage" and b > 1 and backend == "pallas":
+        paths = _stage_paths(
+            n, b, nb, config.spectrum.index_range(n)[1], config=config,
+            tridiag=tridiag, platform=platform, bt_group=bt_group,
+        )
 
     pl = EvdPlan(
         n=n,
@@ -320,6 +443,7 @@ def plan(n: int, dtype, config: EvdConfig = EvdConfig()) -> EvdPlan:
         fallback_reason=reason,
         bt_group=bt_group,
         tridiag=tridiag,
+        paths=paths,
     )
     _PLAN_CACHE[key] = pl
     return pl
@@ -358,51 +482,74 @@ def trace_count(pl=None) -> int:
 @partial(jax.jit, static_argnames=("pl", "eigenvectors"))
 def _execute(A: jax.Array, *, pl: EvdPlan, eigenvectors: bool):
     _TRACE_COUNTS[(pl, eigenvectors)] += 1
-    start, count = pl.spectrum_range
     # The backend is baked into the plan (and thus the jit cache key); the
     # scoped pin makes trace-time registry dispatch match it.
     with registry.use_backend(pl.backend), jax.default_matmul_precision(MATMUL_PRECISION):
-        A = 0.5 * (A + A.T)  # enforce symmetry
-        if pl.method == "jacobi":
-            w, V = _deps.jacobi_eigh(A, max_sweeps=pl.config.max_sweeps)
-            w = w[start : start + count]
-            if not eigenvectors:
-                return w
-            return w, V[:, start : start + count]
+        A = _end_stage("begin", A)
+        if pl.method == "two_stage":
+            with jax.named_scope("evd.first_stage"):
+                A = 0.5 * (A + A.T)  # enforce symmetry
+            return _solve(A, pl=pl, eigenvectors=eigenvectors, staged=True)
+        # The one-stage methods: one scope and one end mark, named after
+        # the method.
+        with jax.named_scope(f"evd.{pl.method}"):
+            A = 0.5 * (A + A.T)
+            out = _solve(A, pl=pl, eigenvectors=eigenvectors, staged=False)
+        return _end_stage(pl.method, out)
 
+
+def _solve(A: jax.Array, *, pl: EvdPlan, eigenvectors: bool, staged: bool):
+    """The solve after symmetrisation.  ``staged`` (the two-stage method)
+    scopes and marks bisection and inverse iteration; each mark also carries
+    the values later stages read, so none of their work runs before it."""
+    start, count = pl.spectrum_range
+    if pl.method == "jacobi":
+        w, V = _deps.jacobi_eigh(A, max_sweeps=pl.config.max_sweeps)
+        w = w[start : start + count]
         if not eigenvectors:
-            d, e = _tridiag_pipeline(
-                A, b=pl.b, nb=pl.nb, method=pl.method, chase=pl.config.chase,
-                tridiag=pl.tridiag,
-            )
-            return _deps.eigvalsh_tridiag_range(
-                d, e, start=start, count=count, max_iter=pl.bisect_iters
-            )
+            return w
+        return w, V[:, start : start + count]
 
-        mode = pl.config.backtransform if pl.method == "two_stage" else "scan"
-        d, e, refl = _tridiag_pipeline(
-            A, b=pl.b, nb=pl.nb, method=pl.method, chase=pl.config.chase,
-            return_reflectors=True, merge_reflectors=mode == "blocked",
-            tridiag=pl.tridiag,
-        )
+    def scope(name):
+        return jax.named_scope(f"evd.{name}") if staged else contextlib.nullcontext()
+
+    def mark(name, outs):
+        return _end_stage(name, outs) if staged else outs
+
+    mode = pl.config.backtransform if pl.method == "two_stage" else "scan"
+    d, e, *refl = _tridiag_pipeline(
+        A, b=pl.b, nb=pl.nb, method=pl.method, chase=pl.config.chase,
+        return_reflectors=eigenvectors, merge_reflectors=mode == "blocked",
+        tridiag=pl.tridiag,
+    )
+    with scope("bisection"):
         w = _deps.eigvalsh_tridiag_range(
             d, e, start=start, count=count, max_iter=pl.bisect_iters
         )
-        # Partial spectrum: inverse iteration runs ONE lane per selected
-        # eigenvalue — the eigenvector phase (inverse iteration AND the
-        # back-transform, whose panels are (rows, k)) costs O(k), not O(n).
+    if not eigenvectors:
+        return mark("bisection", w)
+    kind, refl = refl[0]
+    w, d, e, refl = mark("bisection", (w, d, e, refl))
+    # Partial spectrum: inverse iteration runs ONE lane per selected
+    # eigenvalue — the eigenvector phase (inverse iteration AND the
+    # back-transform, whose panels are (rows, k)) costs O(k), not O(n).
+    with scope("inverse_iteration"):
         VT = _deps.eigvecs_inverse_iteration(d, e, w)
-        V = _backtransform(refl, VT, mode=mode, group=pl.bt_group)
-        return w, V
+    VT, w, refl = mark("inverse_iteration", (VT, w, refl))
+    V, w = _backtransform((kind, refl), VT, w, mode=mode, group=pl.bt_group)
+    return w, V
 
 
 @partial(jax.jit, static_argnames=("pl", "p"))
 def _inverse_pth_root(A: jax.Array, eps: jax.Array, *, pl: EvdPlan, p: int):
     _TRACE_COUNTS[(pl, f"inv{p}")] += 1
     w, V = _execute(A, pl=pl, eigenvectors=True)
-    wmax = jnp.maximum(jnp.max(w), 0.0)
-    ridge = eps * jnp.maximum(wmax, 1e-30)
-    w_safe = jnp.maximum(w, 0.0) + ridge
-    root = jnp.power(w_safe, -1.0 / p)
-    with jax.default_matmul_precision(MATMUL_PRECISION):
-        return (V * root[None, :]) @ V.T
+    with jax.named_scope("evd.root"):
+        wmax = jnp.maximum(jnp.max(w), 0.0)
+        ridge = eps * jnp.maximum(wmax, 1e-30)
+        w_safe = jnp.maximum(w, 0.0) + ridge
+        root = jnp.power(w_safe, -1.0 / p)
+        with jax.default_matmul_precision(MATMUL_PRECISION):
+            X = (V * root[None, :]) @ V.T
+    with registry.use_backend(pl.backend):
+        return _end_stage("root", X)

@@ -54,7 +54,6 @@ class TrainLoop:
             if loop_cfg.ckpt_dir
             else None
         )
-        self.step_times: list = []
         self.straggler_events: list = []
 
     def run(self, params: Any, opt_state: Any, start_step: int = 0):
@@ -82,7 +81,6 @@ class TrainLoop:
             )
             loss = float(metrics["loss"])
             dt = time.perf_counter() - t0
-            self.step_times.append(dt)
 
             # ---- NaN guard -------------------------------------------
             if not np.isfinite(loss):

@@ -77,3 +77,36 @@ def test_phase_bounds_hold_at_small_n(capsys, phase, n):
 def test_kernels_in_reads_custom_call_names(line, name):
     other = '  %dot.3 = f32[8,8] dot(%a, %b)'
     assert _chip_smoke().kernels_in("\n".join([other, line])) == [name]
+
+
+@pytest.mark.parametrize(
+    "n,eigenvectors,kernels",
+    [
+        (4096, False, {"syr2k_lower", "fused_panel_update"}),
+        (4096, True, {"syr2k_lower", "fused_panel_update", "backtransform_wy"}),
+        (1024, False, {"syr2k_lower", "fused_panel_update", "bulge_chase_wavefront"}),
+        (1024, True, {"syr2k_lower", "fused_panel_update", "bulge_chase_wavefront",
+                      "backtransform_wy"}),
+    ],
+)
+def test_expected_kernels_read_the_plans_record(monkeypatch, n, eigenvectors, kernels):
+    # On a TPU: the plan's tables and no interpreter.  At n = 4096 the
+    # padded band is over the bulge kernel's VMEM budget, so the chase is
+    # XLA's; the first stage takes syr2k_lower above m = 1280.
+    import jax.numpy as jnp
+
+    from repro.solver import EvdConfig, plan
+
+    monkeypatch.setattr("repro.backend.probe.platform", lambda: "tpu")
+    cfg = EvdConfig(backend="pallas", tridiag="fused")
+    assert _chip_smoke().expected_kernels(n, cfg, eigenvectors) == kernels
+    pl = plan(n, jnp.float32, cfg)
+    assert pl.kernels(eigenvectors) == kernels
+    lines = pl.describe().splitlines()
+    for stage in ("first_stage", "bulge_chase", "backtransform_q2"):
+        line = next(l for l in lines if l.startswith(f"  {stage}: "))
+        for p in pl.paths:
+            if p.stage == stage:
+                assert p.describe() in line
+    # The XLA reference backend runs no kernel.
+    assert not plan(n, jnp.float32, EvdConfig(backend="jnp")).kernels(eigenvectors)
