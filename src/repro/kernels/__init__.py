@@ -3,8 +3,9 @@
   syr2k         — lower-triangular-tile symmetric rank-2k update (paper §5.2)
   fused_panel   — fused panel QR + compact-WY trailing update with the
                   factors VMEM-resident across the trailing sweep (§5.1/§5.2)
-  bulge         — VMEM-resident grouped-wavefront bulge chasing with
-                  optional reflector-log emission (paper §4.2/§5.3)
+  bulge         — VMEM-resident grouped-wavefront bulge chasing, dense or
+                  band-strip layout, with optional reflector-log emission
+                  (paper §4.2/§5.3)
   panel         — fused Householder panel QR in WY form (paper §5.1)
   backtransform — VMEM-resident blocked compact-WY eigenvector
                   back-transform (DESIGN.md §6)

@@ -2,9 +2,10 @@
 
 The GPU implementation keeps two shared-memory blocks per sweep and
 spin-locks between thread blocks.  The TPU translation (DESIGN.md §2) holds
-the ENTIRE padded matrix in VMEM (the working set of bulge chasing is the
-band — small by construction: the paper's whole point is b ≪ n) and walks
-the static wavefront schedule as the Pallas grid:
+the matrix in VMEM — all of it, or only the band strip the chase can touch
+(the working set of bulge chasing is the band — small by construction: the
+paper's whole point is b ≪ n) — and walks the static wavefront schedule as
+the Pallas grid:
 
 * grid = (num_wavefronts, num_cells) — both sequential ("arbitrary"); the
   matrix block index is constant, so it stays resident in VMEM across all
@@ -30,10 +31,33 @@ the static wavefront schedule as the Pallas grid:
   the kernel lane-dense: blocks of 8 wavefront rows, revisited by the
   cells of those 8 wavefronts and written back once.
 
-VMEM: the padded matrix, once as input and once as output (both single-
-buffered), plus the log blocks — see :func:`bulge_vmem_bytes`.  The
-dispatch ceiling is that count against ``repro.kernels.limits``; above it
-the ops wrapper falls back to the XLA wavefront executor (HBM-resident).
+Two layouts of the resident matrix, each its own ``pallas_call``:
+
+* **dense** (``bulge_chase_wavefront``): the padded ``(side, side)`` matrix,
+  once as input and once as output (both single-buffered), plus the log
+  blocks — see :func:`bulge_vmem_bytes`.  It fits the budget up to
+  n ~ 1900 at b = 8.
+* **band strip** (``bulge_chase_strip``): only the 128-column blocks around
+  the diagonal — see :func:`bulge_strip_vmem_bytes` (DESIGN.md §2).
+  Invariants:
+
+  - row ``i`` of the ``(side, L)`` strip, ``L = tw + 128``, in 128-row
+    block ``p = i // 128``, holds columns ``[128(p - 1), 128(p - 1) + L)``
+    of the padded matrix; columns outside the matrix read zero;
+  - every aligned tile has rows ``[ra, ra + tr)`` in blocks ``P = r0 // 128``
+    and ``P + 1`` (``tr <= 128``), and columns ``[128P, 128P + tw)``: rows
+    of block ``P`` find them at strip lanes ``[128, 128 + tw)``, rows of
+    block ``P + 1`` at ``[0, tw)`` — two static lane offsets, so only the
+    row offset is dynamic;
+  - ``side`` holds block ``P + 1`` of the scratch window, so ``ca`` is never
+    clamped;
+  - every entry a window touches lies in its tile, so the strip kernel
+    computes exactly what the dense kernel computes; entries outside the
+    strip are never touched and the ops wrapper takes them from the input.
+
+The ops wrapper (``repro.kernels.ops.bulge_kernel``) tries dense, then
+strip, against the budget of ``repro.kernels.limits``; above both it falls
+back to the XLA wavefront executor (HBM-resident).
 """
 from __future__ import annotations
 
@@ -49,36 +73,74 @@ from repro.core.bulge_chasing import _pad_sizes, num_wavefronts, max_active_swee
 
 from .limits import tile_bytes, vmem_limit_bytes
 
-__all__ = ["bulge_wavefront_pallas", "bulge_chase_pallas", "bulge_vmem_bytes"]
+__all__ = [
+    "bulge_wavefront_pallas",
+    "bulge_chase_pallas",
+    "bulge_vmem_bytes",
+    "bulge_strip_vmem_bytes",
+    "strip_fits_tile",
+    "BULGE_DENSE",
+    "BULGE_STRIP",
+]
+
+# The pallas_call names of the two layouts.
+BULGE_DENSE = "bulge_chase_wavefront"
+BULGE_STRIP = "bulge_chase_strip"
+LANE_BLOCK = 128
 
 
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def _geometry(n: int, b: int, group: int):
+def _geometry(n: int, b: int, group: int, strip: bool = False):
     """Static sizes: (off, scratch0, side, tr, tw, W_total, G, S).
 
     ``side`` is the padded matrix side (a multiple of 128 that holds every
     window); (tr, tw) is the aligned tile that contains any 3b x 3b window.
+    The strip layout adds the block past the scratch window's, so a tile's
+    columns are never clamped.
     """
     off, scratch0, total = _pad_sizes(n, b)
     tr = _round_up(3 * b + 7, 8)
     tw = _round_up(3 * b + 127, 128)
-    side = max(_round_up(total, 128), tw)
+    if strip:
+        side = (scratch0 // LANE_BLOCK + 2) * LANE_BLOCK
+    else:
+        side = max(_round_up(total, 128), tw)
     A = max_active_sweeps(n, b)
     G = max(1, min(int(group), A))
     S = -(-A // G)
     return off, scratch0, side, tr, tw, num_wavefronts(n, b), G, S
 
 
+def _log_vmem_bytes(S: int, G: int, b: int) -> int:
+    return tile_bytes((8, S * G * b), buffers=2) + 2 * tile_bytes((8, S * G), buffers=2)
+
+
 def bulge_vmem_bytes(n: int, b: int, *, group: int = 1, return_log: bool = False) -> int:
-    """VMEM bytes held by :func:`bulge_wavefront_pallas` at (n, b, group)."""
+    """VMEM bytes held by the dense layout of :func:`bulge_wavefront_pallas`."""
     _, _, side, _, _, _, G, S = _geometry(n, b, group)
     nbytes = 2 * tile_bytes((side, side))  # resident input and output matrix
     if return_log:
-        nbytes += tile_bytes((8, S * G * b), buffers=2)
-        nbytes += 2 * tile_bytes((8, S * G), buffers=2)
+        nbytes += _log_vmem_bytes(S, G, b)
+    return nbytes
+
+
+def strip_fits_tile(b: int) -> bool:
+    """Whether every aligned tile at bandwidth ``b`` spans at most two
+    128-row blocks, which the strip layout's two lane offsets assume."""
+    return _round_up(3 * b + 7, 8) <= LANE_BLOCK
+
+
+def bulge_strip_vmem_bytes(
+    n: int, b: int, *, group: int = 1, return_log: bool = False
+) -> int:
+    """VMEM bytes held by the band-strip layout of :func:`bulge_wavefront_pallas`."""
+    _, _, side, _, tw, _, G, S = _geometry(n, b, group, strip=True)
+    nbytes = 2 * tile_bytes((side, tw + LANE_BLOCK))  # resident input and output strip
+    if return_log:
+        nbytes += _log_vmem_bytes(S, G, b)
     return nbytes
 
 
@@ -134,6 +196,27 @@ def _window_update(T, dr, dc, is_first, b: int):
     return Tn, u, li, tau
 
 
+def _strip_tile(ref, ra, r0, tr: int, tw: int):
+    """The aligned (tr, tw) tile at row ``ra``, columns ``128 * (r0 // 128)``,
+    read from the band strip: ``(band, upper, T)``, with ``upper`` the tile
+    rows of block ``r0 // 128`` (the rest are of the next block)."""
+    band = ref[pl.ds(ra, tr), :]
+    rows = ra + lax.broadcasted_iota(jnp.int32, (tr, 1), 0)
+    upper = rows < (r0 // LANE_BLOCK + 1) * LANE_BLOCK
+    T = jnp.where(upper, band[:, LANE_BLOCK:], band[:, :tw])
+    return band, upper, T
+
+
+def _strip_band(band, upper, Tn, tw: int):
+    """``band`` with the updated tile ``Tn`` put back at each row's lane
+    offset, built whole so that one store writes it."""
+    return jnp.where(
+        upper,
+        jnp.concatenate([band[:, :LANE_BLOCK], Tn], axis=1),
+        jnp.concatenate([Tn, band[:, tw:]], axis=1),
+    )
+
+
 def _bulge_kernel(
     bin_ref,
     bout_ref,
@@ -146,6 +229,7 @@ def _bulge_kernel(
     side: int,
     tr: int,
     tw: int,
+    strip: bool,
 ):
     w = pl.program_id(0)
     c = pl.program_id(1)
@@ -170,11 +254,17 @@ def _bulge_kernel(
         kmax_s = (n - 3 - jnp.clip(s, 0, n - 3)) // b
         active = (s >= 0) & (s <= n - 3) & (k >= 0) & (k <= kmax_s)
         r0 = jnp.where(active, off + s + 1 + (k - 1) * b, scratch0)
-        ra = pl.multiple_of(jnp.minimum((r0 // 8) * 8, side - tr), 8)
-        ca = pl.multiple_of(jnp.minimum((r0 // 128) * 128, side - tw), 128)
-        T = bout_ref[pl.ds(ra, tr), pl.ds(ca, tw)]
-        Tn, u, li, tau = _window_update(T, r0 - ra, r0 - ca, k == 0, b)
-        bout_ref[pl.ds(ra, tr), pl.ds(ca, tw)] = Tn
+        if strip:
+            ra = pl.multiple_of((r0 // 8) * 8, 8)
+            band, upper, T = _strip_tile(bout_ref, ra, r0, tr, tw)
+            Tn, u, li, tau = _window_update(T, r0 - ra, r0 % LANE_BLOCK, k == 0, b)
+            bout_ref[pl.ds(ra, tr), :] = _strip_band(band, upper, Tn, tw)
+        else:
+            ra = pl.multiple_of(jnp.minimum((r0 // 8) * 8, side - tr), 8)
+            ca = pl.multiple_of(jnp.minimum((r0 // 128) * 128, side - tw), 128)
+            T = bout_ref[pl.ds(ra, tr), pl.ds(ca, tw)]
+            Tn, u, li, tau = _window_update(T, r0 - ra, r0 - ca, k == 0, b)
+            bout_ref[pl.ds(ra, tr), pl.ds(ca, tw)] = Tn
         if log_refs:
             # v = u[b:2b] lands in lanes [a*b, a*b + b) of this wavefront's row.
             e = lv - a * b
@@ -194,13 +284,47 @@ def _bulge_kernel(
         row0_ref[...] = row0_blk
 
 
-@functools.partial(jax.jit, static_argnames=("b", "group", "return_log", "interpret"))
+def _pack_strip(B: jax.Array, off: int, side: int, tw: int) -> jax.Array:
+    """(side, tw + 128) strip of ``B`` placed at (off, off) in a zero
+    (side, side) matrix: row block p holds columns [128(p - 1), 128(p - 1) + L)."""
+    n = B.shape[0]
+    L = tw + LANE_BLOCK
+    # Column c of the padded matrix sits at column c + 128, so block -1 and
+    # the columns past the end read zero.
+    left = off + LANE_BLOCK
+    Bq = jnp.pad(B, ((off, side - off - n), (left, side + tw - n - left)))
+    return jnp.concatenate([
+        lax.slice(Bq, (r, r), (r + LANE_BLOCK, r + L))
+        for r in range(0, side, LANE_BLOCK)
+    ])
+
+
+def _unpack_strip(strip: jax.Array, B: jax.Array, off: int, side: int, tw: int) -> jax.Array:
+    """The (n, n) matrix of ``strip`` (the inverse of :func:`_pack_strip`);
+    entries the strip does not hold are taken from ``B``."""
+    n = B.shape[0]
+    L = tw + LANE_BLOCK
+    Dq = jnp.concatenate([
+        jnp.pad(strip[r:r + LANE_BLOCK], ((0, 0), (r, side + tw - r - L)))
+        for r in range(0, side, LANE_BLOCK)
+    ])
+    D = lax.slice(Dq, (off, off + LANE_BLOCK), (off + n, off + LANE_BLOCK + n))
+    i = lax.broadcasted_iota(jnp.int32, (n, n), 0) + off
+    j = lax.broadcasted_iota(jnp.int32, (n, n), 1) + off
+    lo = (i // LANE_BLOCK - 1) * LANE_BLOCK
+    return jnp.where((j >= lo) & (j < lo + L), D, B)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("b", "group", "return_log", "strip", "interpret")
+)
 def bulge_wavefront_pallas(
     B: jax.Array,
     b: int,
     *,
     group: int = 1,
     return_log: bool = False,
+    strip: bool = False,
     interpret: bool = False,
 ):
     """Band (dense storage, bandwidth b) -> tridiagonal, VMEM-resident.
@@ -213,27 +337,36 @@ def bulge_wavefront_pallas(
 
     ``group`` is the number of bulges chased per grid cell (autotuned
     per-platform); the wavefront's ``A`` slots are tiled by
-    ``S = ceil(A / group)`` cells.
+    ``S = ceil(A / group)`` cells.  ``strip`` holds the band strip in VMEM
+    instead of the dense matrix (``bulge_chase_strip``); both layouts give
+    the same result.
     """
     n = B.shape[0]
     if n < 3 or b <= 1:
         if return_log:
             raise ValueError("trivial chase emits no log; handle n < 3 in the caller")
         return B
-    off, scratch0, side, tr, tw, W_total, G, S = _geometry(n, b, group)
+    if strip and not strip_fits_tile(b):
+        raise ValueError(f"the band-strip layout needs 3b + 7 <= 128, got b = {b}")
+    off, scratch0, side, tr, tw, W_total, G, S = _geometry(n, b, group, strip)
     W_pad = _round_up(W_total, 8)
 
-    Bp = jnp.zeros((side, side), B.dtype)
-    Bp = lax.dynamic_update_slice(Bp, B, (off, off))
+    if strip:
+        Bp = _pack_strip(B, off, side, tw)
+        count = bulge_strip_vmem_bytes
+    else:
+        Bp = jnp.zeros((side, side), B.dtype)
+        Bp = lax.dynamic_update_slice(Bp, B, (off, off))
+        count = bulge_vmem_bytes
 
     kernel = functools.partial(
         _bulge_kernel, n=n, b=b, G=G, off=off, scratch0=scratch0,
-        side=side, tr=tr, tw=tw,
+        side=side, tr=tr, tw=tw, strip=strip,
     )
     resident = pl.BlockSpec(
-        (side, side), lambda w, c: (0, 0), pipeline_mode=pl.Buffered(1)
+        Bp.shape, lambda w, c: (0, 0), pipeline_mode=pl.Buffered(1)
     )
-    out_shape = [jax.ShapeDtypeStruct((side, side), B.dtype)]
+    out_shape = [jax.ShapeDtypeStruct(Bp.shape, B.dtype)]
     out_specs = [resident]
     if return_log:
         out_shape += [
@@ -256,13 +389,16 @@ def bulge_wavefront_pallas(
             dimension_semantics=("arbitrary", "arbitrary"),
             # Above the v5e's 16 MiB default scoped VMEM from n ~ 1000 on.
             vmem_limit_bytes=vmem_limit_bytes(
-                bulge_vmem_bytes(n, b, group=group, return_log=return_log)
+                count(n, b, group=group, return_log=return_log)
             ),
         ),
         interpret=interpret,
-        name="bulge_chase_wavefront",
+        name=BULGE_STRIP if strip else BULGE_DENSE,
     )(Bp)
-    out = lax.dynamic_slice(res[0], (off, off), (n, n))
+    if strip:
+        out = _unpack_strip(res[0], B, off, side, tw)
+    else:
+        out = lax.dynamic_slice(res[0], (off, off), (n, n))
     if return_log:
         vs = res[1][:W_total].reshape(W_total, S * G, b)
         return out, (vs, res[2][:W_total], res[3][:W_total])

@@ -19,11 +19,12 @@ Every VMEM-resident kernel in this package dispatches on two numbers:
 
 The budget is a quarter of the 128 MiB of VMEM a TPU v5e core has.  At
 b = 8, nb = 256 it admits the fused panel kernel on trailing views up to
-m = 1280 (m = 1536 counts 36 MiB), and the bulge and Q2 back-transform
-kernels with vectors up to n ~ 1900.  It is twice the compiler's default
-scoped limit (16 MiB on a v5e), so every kernel passes its own limit: the
-count plus :data:`VMEM_HEADROOM_BYTES` for Mosaic's internal scratch
-(:func:`vmem_limit_bytes`).
+m = 1280 (m = 1536 counts 36 MiB), the dense-resident bulge kernel up to
+n ~ 1900 and its band-strip layout up to n ~ 10,700 (12.75 MiB at
+n = 4096); the Q2 back-transform tiles its columns.  It is twice the
+compiler's default scoped limit (16 MiB on a v5e), so every kernel passes
+its own limit: the count plus :data:`VMEM_HEADROOM_BYTES` for Mosaic's
+internal scratch (:func:`vmem_limit_bytes`).
 
 Every entry of :data:`LIMITS` can be overridden with an environment variable
 ``REPRO_<NAME>`` (e.g. ``REPRO_BULGE_INTERPRET_MAX_N=128``) — read at call

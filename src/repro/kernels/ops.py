@@ -24,7 +24,14 @@ from repro.backend import probe
 
 from .limits import fits_vmem, limit
 from .syr2k import syr2k_lower_pallas
-from .bulge import bulge_vmem_bytes, bulge_wavefront_pallas
+from .bulge import (
+    BULGE_DENSE,
+    BULGE_STRIP,
+    bulge_strip_vmem_bytes,
+    bulge_vmem_bytes,
+    bulge_wavefront_pallas,
+    strip_fits_tile,
+)
 from .panel import panel_qr_pallas
 from .fused_panel import fused_panel_update_pallas, fused_tpu_aligned, fused_vmem_bytes
 from .backtransform import backtransform_wy_pallas, column_block
@@ -38,6 +45,9 @@ __all__ = [
     "bulge_chase",
     "bulge_wavefront",
     "bulge_uses_kernel",
+    "bulge_kernel",
+    "BULGE_DENSE",
+    "BULGE_STRIP",
     "panel_qr",
     "backtransform_wy",
     "backtransform_uses_kernel",
@@ -158,10 +168,11 @@ def bulge_uses_kernel(
     return_log: bool = False,
     interpret: Optional[bool] = None,
 ) -> bool:
-    """Whether :func:`bulge_chase` / :func:`bulge_wavefront` at size ``n``
-    run the Pallas kernel (True) or the XLA wavefront fallback (False).
-    Single source of truth for the dispatch decision — benchmarks and
-    diagnostics must use this rather than re-deriving the ceilings.
+    """Whether the dense-resident kernel runs at size ``n``: for
+    :func:`bulge_chase` the choice between it (True) and the XLA wavefront
+    fallback (False), for :func:`bulge_wavefront` the first choice of
+    :func:`bulge_kernel`.  Benchmarks and diagnostics must use these rather
+    than re-deriving the ceilings.
     """
     if n < 3 or b <= 1:
         return False
@@ -171,6 +182,30 @@ def bulge_uses_kernel(
         return n <= limit("BULGE_INTERPRET_MAX_N")
     group = _wavefront_group(n, b) if group is None else group
     return fits_vmem(bulge_vmem_bytes(n, b, group=group, return_log=return_log))
+
+
+def bulge_kernel(
+    n: int,
+    b: int,
+    *,
+    group: Optional[int] = None,
+    return_log: bool = False,
+    interpret: Optional[bool] = None,
+) -> Optional[str]:
+    """The kernel :func:`bulge_wavefront` runs at size ``n``:
+    :data:`BULGE_DENSE` when the dense-resident matrix fits the VMEM budget,
+    else :data:`BULGE_STRIP` when the band strip does, else None (the XLA
+    wavefront executor).  Single source of truth for that choice.
+    """
+    group = _wavefront_group(n, b) if (group is None and n >= 3) else group
+    if bulge_uses_kernel(n, b, group=group, return_log=return_log, interpret=interpret):
+        return BULGE_DENSE
+    if n < 3 or b <= 1 or not strip_fits_tile(b):
+        return None
+    if interpret is None and probe.interpret_mode():
+        return None  # implied interpretation: above the interpret ceiling
+    nbytes = bulge_strip_vmem_bytes(n, b, group=group, return_log=return_log)
+    return BULGE_STRIP if fits_vmem(nbytes) else None
 
 
 def _wavefront_group(n: int, b: int) -> int:
@@ -208,23 +243,24 @@ def bulge_wavefront(
     The fused-mode registry op: the kernel chases ``group`` bulges per grid
     cell (default: the per-platform ``repro.solver.autotune.wavefront_group``)
     and can emit the sweep-major ``ChaseLog`` directly, so eigenvector runs
-    stay on the kernel path.  Above the VMEM/interpret ceilings — or for
-    trivial sizes — it falls back to the slice-write XLA wavefront executor.
+    stay on the kernel path.  It holds the dense matrix in VMEM where that
+    fits and the band strip where only the strip does
+    (:func:`bulge_kernel`).  Above both VMEM counts or the interpret
+    ceiling — or for trivial sizes — it falls back to the slice-write XLA
+    wavefront executor.
     """
     n = B.shape[0]
     from repro.core.bulge_chasing import ChaseLog, chase_wavefront_slices
 
     group = _wavefront_group(n, b) if (group is None and n >= 3) else group
-    if not bulge_uses_kernel(
-        n, b, group=group, return_log=return_log, interpret=interpret
-    ):
+    kernel = bulge_kernel(n, b, group=group, return_log=return_log, interpret=interpret)
+    if kernel is None:
         return chase_wavefront_slices(B, b, return_log)
     interpret = probe.interpret_mode() if interpret is None else interpret
+    kw = dict(group=int(group), strip=kernel == BULGE_STRIP, interpret=interpret)
     if not return_log:
-        return bulge_wavefront_pallas(B, b, group=int(group), interpret=interpret)
-    out, (vs, taus, row0) = bulge_wavefront_pallas(
-        B, b, group=int(group), return_log=True, interpret=interpret
-    )
+        return bulge_wavefront_pallas(B, b, **kw)
+    out, (vs, taus, row0) = bulge_wavefront_pallas(B, b, return_log=True, **kw)
     return out, ChaseLog(vs=vs, taus=taus, row0=row0, n=n, b=b)
 
 

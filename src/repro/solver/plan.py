@@ -260,14 +260,12 @@ def _stage_paths(n, b, nb, k, *, config, tridiag, platform, bt_group):
 
     for vectors in (False, True):
         if config.chase != "wavefront" or (tridiag == "unfused" and vectors):
-            kernel = False
+            kernel = None
         elif tridiag == "unfused":
-            kernel = ops.bulge_uses_kernel(n, b, group=1)
+            kernel = ops.BULGE_DENSE if ops.bulge_uses_kernel(n, b, group=1) else None
         else:
-            kernel = ops.bulge_uses_kernel(n, b, return_log=vectors)
-        paths.append(StagePath(
-            "bulge_chase", "bulge_chase_wavefront" if kernel else XLA, eigenvectors=vectors
-        ))
+            kernel = ops.bulge_kernel(n, b, return_log=vectors)
+        paths.append(StagePath("bulge_chase", kernel or XLA, eigenvectors=vectors))
 
     kernel = (
         config.backtransform == "blocked"

@@ -82,8 +82,9 @@ def test_kernels_in_reads_custom_call_names(line, name):
 @pytest.mark.parametrize(
     "n,eigenvectors,kernels",
     [
-        (4096, False, {"syr2k_lower", "fused_panel_update"}),
-        (4096, True, {"syr2k_lower", "fused_panel_update", "backtransform_wy"}),
+        (4096, False, {"syr2k_lower", "fused_panel_update", "bulge_chase_strip"}),
+        (4096, True, {"syr2k_lower", "fused_panel_update", "bulge_chase_strip",
+                      "backtransform_wy"}),
         (1024, False, {"syr2k_lower", "fused_panel_update", "bulge_chase_wavefront"}),
         (1024, True, {"syr2k_lower", "fused_panel_update", "bulge_chase_wavefront",
                       "backtransform_wy"}),
@@ -91,8 +92,9 @@ def test_kernels_in_reads_custom_call_names(line, name):
 )
 def test_expected_kernels_read_the_plans_record(monkeypatch, n, eigenvectors, kernels):
     # On a TPU: the plan's tables and no interpreter.  At n = 4096 the
-    # padded band is over the bulge kernel's VMEM budget, so the chase is
-    # XLA's; the first stage takes syr2k_lower above m = 1280.
+    # padded matrix is over the VMEM budget and its band strip is not, so
+    # the chase holds the strip; the first stage takes syr2k_lower above
+    # m = 1280.
     import jax.numpy as jnp
 
     from repro.solver import EvdConfig, plan

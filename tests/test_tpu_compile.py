@@ -2,10 +2,11 @@
 
 Nothing here needs a chip: the TPU compiler compiles for a described
 ``v5e:2x2`` topology, at the sizes the default plan dispatches to (b = 8,
-nb = 256, n up to 1024 for the bulge and Q2 kernels, trailing views up to
-the fused kernel's VMEM boundary m = 1280).  Each test asserts that the
-kernel is in the compiled program, which is only true when Mosaic accepted
-its block shapes, its lowering and its VMEM limit.
+nb = 256, n up to 1024 for the dense bulge and Q2 kernels, n = 4096 for the
+band-strip bulge kernel, trailing views up to the fused kernel's VMEM
+boundary m = 1280).  Each test asserts that the kernel is in the compiled
+program, which is only true when Mosaic accepted its block shapes, its
+lowering and its VMEM limit.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, so a worker that is not given this file
@@ -19,7 +20,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.backtransform import _sweep_shape
 from repro.kernels.backtransform import backtransform_wy_pallas
-from repro.kernels.bulge import bulge_wavefront_pallas
+from repro.kernels.bulge import bulge_strip_vmem_bytes, bulge_vmem_bytes, bulge_wavefront_pallas
+from repro.kernels.limits import fits_vmem
 from repro.kernels.fused_panel import fused_panel_update_pallas
 from repro.kernels.syr2k import syr2k_lower_pallas
 
@@ -82,6 +84,23 @@ def test_bulge_wavefront_log_vmapped_compiles(one_chip):
     fn = jax.vmap(lambda Bb: bulge_wavefront_pallas(Bb, B, return_log=True))
     text = _compiled_text(fn, _spec((8, 1024, 1024), one_chip))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("return_log", [False, True], ids=["values", "log"])
+def test_bulge_strip_compiles_at_4096(one_chip, return_log):
+    text = _compiled_text(
+        lambda Bb: bulge_wavefront_pallas(Bb, B, return_log=return_log, strip=True),
+        _spec((4096, 4096), one_chip),
+    )
+    assert "tpu_custom_call" in text
+    assert "bulge_chase_strip" in text
+
+
+@pytest.mark.parametrize("return_log", [False, True], ids=["values", "log"])
+def test_bulge_vmem_counts_at_4096(return_log):
+    # The strip fits the budget where the dense-resident matrix does not.
+    assert fits_vmem(bulge_strip_vmem_bytes(4096, B, return_log=return_log))
+    assert not fits_vmem(bulge_vmem_bytes(4096, B, return_log=return_log))
 
 
 def test_backtransform_wy_compiles(one_chip):
