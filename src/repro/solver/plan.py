@@ -369,6 +369,10 @@ class EvdPlan:
         for p in self.paths:
             stages.setdefault(p.stage, []).append(p.describe())
         parts += [f"  {stage}: " + "; ".join(ds) for stage, ds in stages.items()]
+        if self.method != "jacobi":
+            from repro.core.tridiag_eig import INVERSE_ITERATION_STEPS
+
+            parts.append(f"  inverse_iteration: {INVERSE_ITERATION_STEPS} steps (vectors)")
         return "\n".join(parts)
 
     def kernels(self, eigenvectors: bool) -> frozenset:
