@@ -7,29 +7,33 @@ the matrix in VMEM — all of it, or only the band strip the chase can touch
 paper's whole point is b ≪ n) — and walks the static wavefront schedule as
 the Pallas grid:
 
-* grid = (num_wavefronts, num_cells) — both sequential ("arbitrary"); the
-  matrix block index is constant, so it stays resident in VMEM across all
-  wavefronts and is written back to HBM once at the end.  This is the
-  paper's "hide the data movement" taken to its limit: one load, one store.
-* each grid cell chases a GROUP of G independent bulges of the wavefront:
-  the cells of a wavefront tile its ``A = max_active_sweeps`` slots, and
-  each slot applies one 3b x 3b two-sided Householder window update in
-  place.  Window disjointness within a wavefront — the same invariant that
-  makes the XLA executor's batched update race-free — makes the cell order
-  irrelevant.  Masked slots are routed to a zero scratch corner and
-  degenerate to tau = 0 no-ops, so the schedule needs no branches.
+* grid = (num_wavefronts,) — sequential ("arbitrary"); the matrix block
+  index is constant, so it stays resident in VMEM across all wavefronts and
+  is written back to HBM once at the end.  This is the paper's "hide the
+  data movement" taken to its limit: one load, one store.
+* each step walks only the LIVE slots of its wavefront: slot ``a`` chases
+  sweep ``w//3 - a``, and the slots whose sweep has started and not yet
+  ended form one range ``[lo(w), hi(w)]`` with a closed form
+  (:func:`_live_slots`), about half of the ``A = max_active_sweeps`` slots.
+  A ``fori_loop`` chases the range in GROUPS of G bulges, each applying one
+  3b x 3b two-sided Householder window update in place.  Window
+  disjointness within a wavefront — the same invariant that makes the XLA
+  executor's batched update race-free — makes the loop order irrelevant.
+  A group's tail slots past ``hi(w)`` are routed to a zero scratch corner
+  and degenerate to tau = 0 no-ops, so a group needs no branches.
 * the TPU loads and stores VMEM at (8, 128)-aligned offsets only, and a
   window starts at any row.  So each slot loads the aligned (TR, TW) tile
   that contains its window, updates the window inside it with masks (rows
   and columns outside the window are written back unchanged), and stores
   the tile.  All vectors are kept 2-D — columns (TR, 1) and rows (1, TW) —
   and a column becomes a row by a masked reduction, which is exact.
-* with ``return_log=True`` each cell also writes the reflector log (v, tau,
-  row0) for its slots, laid out like ``chase_wavefront``'s (W, A, b)
+* with ``return_log=True`` each step also writes the reflector log (v, tau,
+  row0) of its wavefront, laid out like ``chase_wavefront``'s (W, A, b)
   sweep-major log, so the eigenvector path (``apply_q2`` and the
-  back-transform regroup) consumes kernel logs unchanged.  The log leaves
-  the kernel lane-dense: blocks of 8 wavefront rows, revisited by the
-  cells of those 8 wavefronts and written back once.
+  back-transform regroup) consumes kernel logs unchanged.  The step writes
+  its whole row: slots outside the live range read v = 0, tau = 0 and
+  row0 = n.  The log leaves the kernel lane-dense: blocks of 8 wavefront
+  rows, revisited by the steps of those 8 wavefronts and written back once.
 
 Two layouts of the resident matrix, each its own ``pallas_call``:
 
@@ -65,6 +69,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -77,6 +82,7 @@ __all__ = [
     "bulge_wavefront_pallas",
     "bulge_chase_pallas",
     "bulge_vmem_bytes",
+    "chase_slot_counts",
     "bulge_strip_vmem_bytes",
     "strip_fits_tile",
     "BULGE_DENSE",
@@ -217,12 +223,40 @@ def _strip_band(band, upper, Tn, tw: int):
     )
 
 
+def _live_slots(w, n: int, b: int, A: int, xp=jnp):
+    """The live slots ``[lo, hi]`` of wavefront ``w`` (empty when hi < lo).
+
+    Slot ``a`` chases sweep ``s = w//3 - a`` at step ``k = w%3 + 3a``; it
+    is live while ``0 <= s <= n - 3`` and ``b k <= n - 3 - s``, which bounds
+    ``a`` below by ``w//3 - (n - 3)`` and above by
+    ``(n - 3 - w//3 - b (w%3)) / (3b - 1)``.  ``xp`` is ``jnp`` in the
+    kernel (scalar arithmetic on the program id) and ``np`` on the host.
+    """
+    q, r = w // 3, w % 3
+    lo = xp.maximum(q - (n - 3), 0)
+    num = n - 3 - q - b * r
+    hi = xp.minimum(xp.minimum(q, A - 1), xp.maximum(num, 0) // (3 * b - 1))
+    return lo, xp.where(num < 0, -1, hi)
+
+
+def chase_slot_counts(n: int, b: int, group: int = 1):
+    """``(live, visited, rectangle)`` window updates of one chase: the live
+    slots, the slots the kernel visits (each wavefront's live range rounded
+    up to whole groups of ``group``), and the ``(W, S*G)`` rectangle of
+    every slot of every wavefront."""
+    _, _, _, _, _, W, G, S = _geometry(n, b, group)
+    lo, hi = _live_slots(np.arange(W), n, b, max_active_sweeps(n, b), xp=np)
+    live = np.maximum(hi - lo + 1, 0)
+    return int(live.sum()), int((-(-live // G) * G).sum()), W * S * G
+
+
 def _bulge_kernel(
     bin_ref,
     bout_ref,
     *log_refs,
     n: int,
     b: int,
+    A: int,
     G: int,
     off: int,
     scratch0: int,
@@ -232,56 +266,65 @@ def _bulge_kernel(
     strip: bool,
 ):
     w = pl.program_id(0)
-    c = pl.program_id(1)
 
-    @pl.when((w == 0) & (c == 0))
+    @pl.when(w == 0)
     def _copy_in():
         bout_ref[...] = bin_ref[...]
 
+    a_lo, a_hi = _live_slots(w, n, b, A)
+    q, r = w // 3, w % 3
     if log_refs:
         vs_ref, taus_ref, row0_ref = log_refs
         wrow = lax.broadcasted_iota(jnp.int32, (8, 1), 0) == w % 8
-        vs_blk = vs_ref[...]
-        taus_blk = taus_ref[...]
-        row0_blk = row0_ref[...]
-        lv = lax.broadcasted_iota(jnp.int32, (1, vs_blk.shape[1]), 1)
-        lt = lax.broadcasted_iota(jnp.int32, (1, taus_blk.shape[1]), 1)
+        lv = lax.broadcasted_iota(jnp.int32, (1, vs_ref.shape[1]), 1)
+        lt = lax.broadcasted_iota(jnp.int32, (1, taus_ref.shape[1]), 1)
 
-    for g in range(G):  # static unroll over the cell's bulge group
-        a = c * G + g  # wavefront slot chased by this (cell, lane)
-        s = w // 3 - a
-        k = w - 3 * s
-        kmax_s = (n - 3 - jnp.clip(s, 0, n - 3)) // b
-        active = (s >= 0) & (s <= n - 3) & (k >= 0) & (k <= kmax_s)
-        r0 = jnp.where(active, off + s + 1 + (k - 1) * b, scratch0)
-        if strip:
-            ra = pl.multiple_of((r0 // 8) * 8, 8)
-            band, upper, T = _strip_tile(bout_ref, ra, r0, tr, tw)
-            Tn, u, li, tau = _window_update(T, r0 - ra, r0 % LANE_BLOCK, k == 0, b)
-            bout_ref[pl.ds(ra, tr), :] = _strip_band(band, upper, Tn, tw)
-        else:
-            ra = pl.multiple_of(jnp.minimum((r0 // 8) * 8, side - tr), 8)
-            ca = pl.multiple_of(jnp.minimum((r0 // 128) * 128, side - tw), 128)
-            T = bout_ref[pl.ds(ra, tr), pl.ds(ca, tw)]
-            Tn, u, li, tau = _window_update(T, r0 - ra, r0 - ca, k == 0, b)
-            bout_ref[pl.ds(ra, tr), pl.ds(ca, tw)] = Tn
-        if log_refs:
-            # v = u[b:2b] lands in lanes [a*b, a*b + b) of this wavefront's row.
-            e = lv - a * b
-            v_row = jnp.sum(
-                jnp.where((li - b == e) & (e >= 0) & (e < b), u, 0.0),
-                axis=0, keepdims=True,
-            )
-            vs_blk = jnp.where(wrow & (e >= 0) & (e < b), v_row, vs_blk)
-            slot = wrow & (lt == a)
-            taus_blk = jnp.where(slot, tau, taus_blk)
-            row0 = jnp.where(active, s + 1 + k * b, n).astype(jnp.int32)
-            row0_blk = jnp.where(slot, row0, row0_blk)
+    def chase(i, logs):
+        for g in range(G):  # static unroll over the iteration's bulge group
+            a = a_lo + i * G + g  # wavefront slot chased by this (iteration, lane)
+            s = q - a
+            k = r + 3 * a
+            active = a <= a_hi
+            r0 = jnp.where(active, off + s + 1 + (k - 1) * b, scratch0)
+            if strip:
+                ra = pl.multiple_of((r0 // 8) * 8, 8)
+                band, upper, T = _strip_tile(bout_ref, ra, r0, tr, tw)
+                Tn, u, li, tau = _window_update(T, r0 - ra, r0 % LANE_BLOCK, k == 0, b)
+                bout_ref[pl.ds(ra, tr), :] = _strip_band(band, upper, Tn, tw)
+            else:
+                ra = pl.multiple_of(jnp.minimum((r0 // 8) * 8, side - tr), 8)
+                ca = pl.multiple_of(jnp.minimum((r0 // 128) * 128, side - tw), 128)
+                T = bout_ref[pl.ds(ra, tr), pl.ds(ca, tw)]
+                Tn, u, li, tau = _window_update(T, r0 - ra, r0 - ca, k == 0, b)
+                bout_ref[pl.ds(ra, tr), pl.ds(ca, tw)] = Tn
+            if log_refs:
+                # v = u[b:2b] lands in lanes [a*b, a*b + b) of this wavefront's
+                # row; a masked tail slot leaves the row as it starts.
+                vs_blk, taus_blk, row0_blk = logs
+                e = lv - a * b
+                lanes = wrow & (e >= 0) & (e < b) & active
+                v_row = jnp.sum(jnp.where(li - b == e, u, 0.0), axis=0, keepdims=True)
+                slot = wrow & (lt == a) & active
+                logs = (
+                    jnp.where(lanes, v_row, vs_blk),
+                    jnp.where(slot, tau, taus_blk),
+                    jnp.where(slot, s + 1 + k * b, row0_blk),
+                )
+        return logs
 
-    if log_refs:
-        vs_ref[...] = vs_blk
-        taus_ref[...] = taus_blk
-        row0_ref[...] = row0_blk
+    steps = (jnp.maximum(a_hi - a_lo + 1, 0) + G - 1) // G
+    if not log_refs:
+        lax.fori_loop(0, steps, chase, ())
+        return
+
+    # The step writes all of its wavefront's row: the slots the loop does not
+    # visit read v = 0, tau = 0 and row0 = n, the log of an inactive slot.
+    logs = (
+        jnp.where(wrow, 0.0, vs_ref[...]),
+        jnp.where(wrow, 0.0, taus_ref[...]),
+        jnp.where(wrow, n, row0_ref[...]),
+    )
+    vs_ref[...], taus_ref[...], row0_ref[...] = lax.fori_loop(0, steps, chase, logs)
 
 
 def _pack_strip(B: jax.Array, off: int, side: int, tw: int) -> jax.Array:
@@ -332,12 +375,13 @@ def bulge_wavefront_pallas(
     Matches ``repro.core.chase_wavefront`` up to float rounding; with
     ``return_log=True`` also returns the raw sweep-major log arrays
     ``(vs, taus, row0)`` shaped ``(W, S*group, b)`` / ``(W, S*group)`` —
-    slot-compatible with the XLA executor's ``(W, A, b)`` log (slots past
-    ``A`` are masked no-ops; the ops wrapper wraps them in a ``ChaseLog``).
+    slot-compatible with the XLA executor's ``(W, A, b)`` log (slots that
+    are not live, those past ``A`` among them, are no-ops; the ops wrapper
+    wraps them in a ``ChaseLog``).
 
-    ``group`` is the number of bulges chased per grid cell (autotuned
-    per-platform); the wavefront's ``A`` slots are tiled by
-    ``S = ceil(A / group)`` cells.  ``strip`` holds the band strip in VMEM
+    ``group`` is the number of bulges chased per loop iteration (autotuned
+    per-platform); the log keeps ``S = ceil(A / group)`` groups of lanes
+    per wavefront.  ``strip`` holds the band strip in VMEM
     instead of the dense matrix (``bulge_chase_strip``); both layouts give
     the same result.
     """
@@ -360,11 +404,11 @@ def bulge_wavefront_pallas(
         count = bulge_vmem_bytes
 
     kernel = functools.partial(
-        _bulge_kernel, n=n, b=b, G=G, off=off, scratch0=scratch0,
-        side=side, tr=tr, tw=tw, strip=strip,
+        _bulge_kernel, n=n, b=b, A=max_active_sweeps(n, b), G=G, off=off,
+        scratch0=scratch0, side=side, tr=tr, tw=tw, strip=strip,
     )
     resident = pl.BlockSpec(
-        Bp.shape, lambda w, c: (0, 0), pipeline_mode=pl.Buffered(1)
+        Bp.shape, lambda w: (0, 0), pipeline_mode=pl.Buffered(1)
     )
     out_shape = [jax.ShapeDtypeStruct(Bp.shape, B.dtype)]
     out_specs = [resident]
@@ -375,18 +419,18 @@ def bulge_wavefront_pallas(
             jax.ShapeDtypeStruct((W_pad, S * G), jnp.int32),
         ]
         out_specs += [
-            pl.BlockSpec((8, S * G * b), lambda w, c: (w // 8, 0)),
-            pl.BlockSpec((8, S * G), lambda w, c: (w // 8, 0)),
-            pl.BlockSpec((8, S * G), lambda w, c: (w // 8, 0)),
+            pl.BlockSpec((8, S * G * b), lambda w: (w // 8, 0)),
+            pl.BlockSpec((8, S * G), lambda w: (w // 8, 0)),
+            pl.BlockSpec((8, S * G), lambda w: (w // 8, 0)),
         ]
     res = pl.pallas_call(
         kernel,
-        grid=(W_total, S),
+        grid=(W_total,),
         in_specs=[resident],
         out_specs=out_specs,
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
+            dimension_semantics=("arbitrary",),
             # Above the v5e's 16 MiB default scoped VMEM from n ~ 1000 on.
             vmem_limit_bytes=vmem_limit_bytes(
                 count(n, b, group=group, return_log=return_log)

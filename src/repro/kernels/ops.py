@@ -240,8 +240,8 @@ def bulge_wavefront(
 ):
     """Grouped wavefront bulge chase, optionally emitting the reflector log.
 
-    The fused-mode registry op: the kernel chases ``group`` bulges per grid
-    cell (default: the per-platform ``repro.solver.autotune.wavefront_group``)
+    The fused-mode registry op: the kernel chases ``group`` bulges per loop
+    iteration (default: the per-platform ``repro.solver.autotune.wavefront_group``)
     and can emit the sweep-major ``ChaseLog`` directly, so eigenvector runs
     stay on the kernel path.  It holds the dense matrix in VMEM where that
     fits and the band strip where only the strip does

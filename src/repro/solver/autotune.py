@@ -79,12 +79,11 @@ _BT_GROUP_TABLE = {
 }
 
 # Fused-chase wavefront group size G: the bulge_wavefront kernel chases G
-# independent bulges per grid cell (repro.kernels.bulge).  On TPU each
-# window update is VPU-bound, so one bulge per cell (the issue's "each
-# bulge's b-row window as a grid cell") keeps cells small and lets the
-# sequential grid overlap scalar setup with compute; under the interpreter
-# every grid cell costs a Python-level step, so grouping several bulges per
-# cell amortizes it.  (n_upper_exclusive | None, G) rows like the tables
+# independent bulges per iteration of its loop over a wavefront's live
+# slots (repro.kernels.bulge).  On TPU each window update is VPU-bound, so
+# one bulge per iteration wastes no update on a group's masked tail; under
+# the interpreter every iteration costs a Python-level step, so grouping
+# several bulges per iteration amortizes it.  (n_upper_exclusive | None, G) rows like the tables
 # above; G is clamped to the wavefront's slot count at dispatch time.
 DEFAULT_WAVEFRONT_GROUP = 1
 _WAVEFRONT_GROUP_TABLE = {

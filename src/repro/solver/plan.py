@@ -35,7 +35,7 @@ from jax import lax
 
 from repro.backend import probe, registry
 
-from .autotune import backtransform_group, resolve_blocking, tile_defaults
+from .autotune import backtransform_group, resolve_blocking, tile_defaults, wavefront_group
 from .config import EvdConfig, Spectrum
 
 __all__ = [
@@ -368,12 +368,31 @@ class EvdPlan:
         stages: Dict[str, list] = {}
         for p in self.paths:
             stages.setdefault(p.stage, []).append(p.describe())
-        parts += [f"  {stage}: " + "; ".join(ds) for stage, ds in stages.items()]
+        chase_kernel = any(p.stage == "bulge_chase" and p.kernel != XLA for p in self.paths)
+        for stage, ds in stages.items():
+            parts.append(f"  {stage}: " + "; ".join(ds))
+            if stage == "bulge_chase" and chase_kernel:
+                parts.append(self._chase_slots())
         if self.method != "jacobi":
             from repro.core.tridiag_eig import INVERSE_ITERATION_STEPS
 
             parts.append(f"  inverse_iteration: {INVERSE_ITERATION_STEPS} steps (vectors)")
         return "\n".join(parts)
+
+    def _chase_slots(self) -> str:
+        """The chase kernel's window updates: live slots against the
+        ``(W, S*G)`` rectangle, and the slots it visits."""
+        from repro.kernels.bulge import chase_slot_counts
+
+        # The unfused pipeline's values-only chase runs one bulge a group.
+        group = 1 if self.tridiag == "unfused" else wavefront_group(
+            self.n, self.b, self.platform
+        )
+        live, visited, rect = chase_slot_counts(self.n, self.b, group)
+        return (
+            f"  bulge_chase slots: {live} of {rect} "
+            f"({100 * live / rect:.1f}%), {visited} visited"
+        )
 
     def kernels(self, eigenvectors: bool) -> frozenset:
         """Names of the Pallas kernels of the stages a solve dispatches to
