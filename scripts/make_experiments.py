@@ -165,7 +165,7 @@ def multipod_section(recs):
 def bench_section(bench_file):
     out = ["## §Benchmarks — raw harness output (one suite per paper table/figure)", ""]
     if not bench_file or not os.path.exists(bench_file):
-        out.append("_run `PYTHONPATH=src python -m benchmarks.run | tee bench_output.txt` and regenerate._")
+        out.append("_no benchmark CSV given (`--bench`); on-chip numbers live in PERF_LEDGER.jsonl._")
         out.append("")
         return out
     rows = [l.strip() for l in open(bench_file) if l.strip() and not l.startswith("#")]

@@ -1,8 +1,8 @@
 """JAX's persistent compilation cache, placeable from outside.
 
-Scripts that compile the solver (``chip_smoke.py``, ``benchmarks/run.py``)
-call :func:`enable_compilation_cache` once at start-up; importing the
-library never touches the cache.
+Scripts that compile the solver (``chip_smoke.py``) call
+:func:`enable_compilation_cache` once at start-up; importing the library
+never touches the cache.
 
 * ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself, and no other
   directory is configured here.

@@ -1,26 +1,19 @@
 """Kernel registry: ONE dispatch point from hot op -> implementation.
 
-The EVD pipeline has three hot ops (the paper's Table 1 decomposition):
+The EVD pipeline resolves five ops here:
 
 * ``trailing_update`` — the DBR rank-2·nb syr2k trailing update
-  (``C - Z Y^T - Y Z^T``), the compute-bound stage-1 workhorse.
-* ``syr2k``           — the general symmetric rank-2k update behind it.
+  (``C - Z Y^T - Y Z^T``), the first stage's step above the fused
+  kernel's ceiling.
 * ``fused_panel_update`` — one whole first-stage block step (panel QRs +
-  trailing update fused, factors VMEM-resident) — the ``tridiag="fused"``
-  stage-1 op; the ``panel_qr`` + ``trailing_update`` composition stays
-  registered as its fallback/oracle.
-* ``bulge_chase``     — band -> tridiagonal wavefront chasing (values-only).
-* ``bulge_wavefront`` — grouped wavefront chasing with optional reflector
-  log (the ``tridiag="fused"`` chase op; eigenvectors stay on the kernel).
-* ``panel_qr``        — the WY-form panel factorization.
+  trailing update fused, factors VMEM-resident); above the kernel's
+  ceiling it runs the panel QRs and ``trailing_update`` in turn.
+* ``bulge_wavefront`` — band -> tridiagonal grouped wavefront chasing,
+  with an optional reflector log (eigenvectors stay on the kernel).
 * ``backtransform_wy`` — the blocked compact-WY eigenvector back-transform
   (sweep-major grouped Q2 application; see ``repro.core.backtransform``).
 * ``stage_mark``      — a named no-op kernel marking a stage boundary on the
   device timeline (``repro.kernels.mark``); the identity under ``jnp``.
-
-This module also owns the process-level ``tridiag`` pipeline default
-(:func:`default_tridiag`): ``REPRO_TRIDIAG=fused|unfused`` mirrors
-``REPRO_KERNEL_BACKEND`` so CI legs can pin the legacy composition.
 
 Each op maps to one of two backends:
 
@@ -43,12 +36,9 @@ from typing import Callable, Dict, Optional, Tuple
 
 __all__ = [
     "ENV_VAR",
-    "TRIDIAG_ENV_VAR",
     "BACKENDS",
     "OPS",
-    "TRIDIAGS",
     "default_backend",
-    "default_tridiag",
     "set_backend",
     "use_backend",
     "validate_backend",
@@ -58,19 +48,14 @@ __all__ = [
 ]
 
 ENV_VAR = "REPRO_KERNEL_BACKEND"
-TRIDIAG_ENV_VAR = "REPRO_TRIDIAG"
 BACKENDS = ("pallas", "jnp")  # built-ins; register() can add more names
 OPS = (
     "trailing_update",
-    "syr2k",
     "fused_panel_update",
-    "bulge_chase",
     "bulge_wavefront",
-    "panel_qr",
     "backtransform_wy",
     "stage_mark",
 )
-TRIDIAGS = ("fused", "unfused")
 
 _override: Optional[str] = None
 _extra_backends: set = set()
@@ -108,23 +93,6 @@ def default_backend() -> str:
     if env:
         return _validate(env)
     return "pallas"
-
-
-def default_tridiag() -> str:
-    """The process-wide first-stage pipeline generation: ``"fused"`` (the
-    restructured schedule — fused panel+trailing op, grouped wavefront
-    chase) unless ``REPRO_TRIDIAG=unfused`` pins the legacy composition
-    (CI's oracle leg does exactly that).  Read at trace time, like
-    :func:`default_backend`.
-    """
-    env = os.environ.get(TRIDIAG_ENV_VAR)
-    if not env:
-        return "fused"
-    if env not in TRIDIAGS:
-        raise ValueError(
-            f"invalid {TRIDIAG_ENV_VAR}={env!r}; expected one of {TRIDIAGS}"
-        )
-    return env
 
 
 def set_backend(backend: Optional[str]) -> None:
@@ -169,14 +137,7 @@ def _build_impls() -> None:
     global _built
     from repro.kernels import ref as kref
     from repro.core.backtransform import backtransform_wy_xla
-    from repro.core.bulge_chasing import chase_wavefront, chase_wavefront_slices
-    from repro.core.panel_qr import panel_qr_geqrf
-
-    def jnp_bulge_chase(B, b):
-        return chase_wavefront(B, b)
-
-    def jnp_bulge_wavefront(B, b, *, return_log=False):
-        return chase_wavefront_slices(B, b, return_log)
+    from repro.core.bulge_chasing import chase_wavefront
 
     def default(op, backend, fn):
         # setdefault semantics: a register() call made before the first
@@ -185,13 +146,8 @@ def _build_impls() -> None:
             register(op, backend, fn)
 
     default("trailing_update", "jnp", kref.trailing_update_ref)
-    default("syr2k", "jnp", kref.syr2k_ref)
-    # The fused jnp path IS the unfused jnp composition (bitwise — same XLA
-    # subgraph), which is exactly what makes it the fused oracle.
     default("fused_panel_update", "jnp", kref.fused_panel_update_ref)
-    default("bulge_chase", "jnp", jnp_bulge_chase)
-    default("bulge_wavefront", "jnp", jnp_bulge_wavefront)
-    default("panel_qr", "jnp", panel_qr_geqrf)
+    default("bulge_wavefront", "jnp", chase_wavefront)
     default("backtransform_wy", "jnp", backtransform_wy_xla)
     default("stage_mark", "jnp", lambda tile, stage: tile)
 
@@ -199,9 +155,6 @@ def _build_impls() -> None:
 
     def pallas_trailing_update(C, Y, Z):
         return kops.trailing_update(C, Y, Z, **tile_defaults("trailing_update"))
-
-    def pallas_syr2k(A, B, C=None, *, alpha: float = 1.0):
-        return kops.syr2k(A, B, C, alpha=alpha, **tile_defaults("syr2k"))
 
     def pallas_fused_panel_update(Bv, b, w):
         return kops.fused_panel_update(
@@ -212,11 +165,8 @@ def _build_impls() -> None:
         return kops.bulge_wavefront(B, b, return_log=return_log)
 
     default("trailing_update", "pallas", pallas_trailing_update)
-    default("syr2k", "pallas", pallas_syr2k)
     default("fused_panel_update", "pallas", pallas_fused_panel_update)
-    default("bulge_chase", "pallas", kops.bulge_chase)
     default("bulge_wavefront", "pallas", pallas_bulge_wavefront)
-    default("panel_qr", "pallas", kops.panel_qr)
     default("backtransform_wy", "pallas", kops.backtransform_wy)
     default("stage_mark", "pallas", kops.stage_mark)
 

@@ -24,16 +24,15 @@ XLA/TPU (one k = w GEMM instead of a recursion tree of launches).  See
 DESIGN.md §2.
 
 Shapes are static per block (Python loop over blocks with shrinking trailing
-views), so everything jits and vmaps.  The trailing update and panel
-factorization are resolved through ``repro.backend.registry`` at trace time,
-so the Pallas ``syr2k`` kernel is the default hot path (interpret-mode on
-CPU, compiled on TPU) with the jnp reference as the always-available
-fallback; pass ``syr2k_update=`` only to inject a custom callable.
+views), so everything jits and vmaps.  Each block step is the registry's
+``fused_panel_update`` op, resolved at trace time: the fused Pallas kernel
+where it fits (interpret-mode on CPU, compiled on TPU), else
+:func:`_reduce_block` with the registry ``trailing_update``; the jnp
+backend runs :func:`_reduce_block` with the jnp trailing update.
 """
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
 from typing import Callable, Optional, Tuple
 
 import jax
@@ -42,7 +41,6 @@ from jax import lax
 
 from repro.backend import registry
 
-from .panel_qr import panel_qr_geqrf, panel_qr_householder
 
 __all__ = [
     "band_reduce",
@@ -121,8 +119,8 @@ class StageSchedule:
     * ``panel0``/``q`` tile the global panel numbering contiguously —
       ``entries[g].panel0 == sum(q of entries[:g])`` — so
       ``BandReflectors.blocks == ((e.panel0, e.q) for e in entries)``
-      regardless of which executor (fused kernel, fused jnp, unfused
-      composition) runs the entries.
+      regardless of which executor (the fused kernel or
+      ``_reduce_block``) runs the entries.
     * every ``w`` is a multiple of ``b`` and ``b <= m - w``, the
       preconditions of both the fused kernel and ``_reduce_block``.
 
@@ -164,7 +162,7 @@ def _reduce_block(
     b: int,
     w: int,
     panel_qr_fn: Callable,
-    syr2k_update: Callable,
+    trailing_update_fn: Callable,
 ):
     """Reduce the first ``w`` columns of the trailing view ``Bv`` (m, m) to
     bandwidth ``b`` and apply one rank-2w trailing update.
@@ -216,7 +214,7 @@ def _reduce_block(
     (Vbuf, Zbuf, F), Ts = lax.scan(panel, (zeros, zeros, zeros), jnp.arange(q))
 
     # --- one rank-2w trailing update with k = w (the paper's big syr2k) -----
-    trailing = syr2k_update(Bv[w:, w:], Vbuf[w:, :], Zbuf[w:, :])
+    trailing = trailing_update_fn(Bv[w:, w:], Vbuf[w:, :], Zbuf[w:, :])
     new_view = Bv
     new_view = new_view.at[w:, w:].set(trailing)
     new_view = new_view.at[:, :w].set(F)
@@ -229,38 +227,24 @@ def band_reduce(
     b: int,
     nb: Optional[int] = None,
     *,
-    panel_method: str = "geqrf",
-    syr2k_update: Optional[Callable] = None,
     return_reflectors: bool = False,
     merge_ts: bool = False,
-    mode: Optional[str] = None,
 ):
     """Reduce a symmetric matrix to band form with bandwidth ``b``.
 
-    ``nb == b`` is conventional SBR; ``nb > b`` is the paper's DBR.
+    ``nb == b`` is conventional SBR; ``nb > b`` is the paper's DBR.  Each
+    :class:`StageSchedule` entry runs as ONE ``fused_panel_update``
+    registry op (panel QRs + trailing update).
 
     Args:
       A: (n, n) symmetric.  ``n`` must be a multiple of ``b``.
       b: target bandwidth (panel width).
       nb: update block size (multiple of ``b``); defaults to ``b`` (SBR).
-      panel_method: "geqrf" | "householder" | "pallas" (registry kernel).
-      syr2k_update: callable (C, Y, Z) -> C - Z Y^T - Y Z^T.  Default: the
-        active ``repro.backend.registry`` trailing-update kernel (Pallas
-        syr2k unless ``REPRO_KERNEL_BACKEND=jnp``).
       return_reflectors: also return :class:`BandReflectors` for Q1.
       merge_ts: with ``return_reflectors``, also fuse each DBR block's
         per-panel T factors into one (q·b, q·b) block-reflector T (stored as
         ``BandReflectors.Tm``) so the blocked back-transform applies rank-q·b
         GEMMs instead of per-panel rank-b updates.
-      mode: "fused" | "unfused" | None (default: the process-wide
-        ``registry.default_tridiag()``).  "fused" executes each
-        :class:`StageSchedule` entry as ONE ``fused_panel_update`` registry
-        op (panel QRs + trailing update in a single kernel, factors
-        VMEM-resident); "unfused" is the legacy panel_qr + syr2k
-        composition, kept as the oracle.  Injecting ``syr2k_update`` or a
-        non-default ``panel_method`` implies the unfused composition (the
-        fused op owns both phases); requesting ``mode="fused"`` alongside
-        them is an error.
 
     Returns:
       ``Bband`` (n, n) symmetric banded, and optionally reflectors.
@@ -272,30 +256,7 @@ def band_reduce(
     if nb % b != 0:
         raise ValueError(f"nb={nb} must be a multiple of b={b}")
 
-    custom_phases = syr2k_update is not None or panel_method != "geqrf"
-    if mode is None:
-        mode = "unfused" if custom_phases else registry.default_tridiag()
-    if mode not in ("fused", "unfused"):
-        raise ValueError(f"unknown band-reduction mode: {mode!r}")
-    if mode == "fused" and custom_phases:
-        raise ValueError(
-            "mode='fused' executes panel QR and the trailing update as one "
-            "op; syr2k_update/panel_method injection requires mode='unfused'"
-        )
-
-    if mode == "fused":
-        fused_update = registry.resolve("fused_panel_update")
-    else:
-        if syr2k_update is None:
-            syr2k_update = registry.resolve("trailing_update")
-        if panel_method == "geqrf":
-            panel_qr_fn = panel_qr_geqrf
-        elif panel_method == "householder":
-            panel_qr_fn = panel_qr_householder
-        elif panel_method == "pallas":
-            panel_qr_fn = registry.resolve("panel_qr", "pallas")
-        else:
-            raise ValueError(f"unknown panel_method: {panel_method!r}")
+    fused_update = registry.resolve("fused_panel_update")
 
     dtype = A.dtype
     B = A
@@ -305,11 +266,7 @@ def band_reduce(
 
     schedule = build_stage_schedule(n, b, nb)
     for e in schedule.entries:
-        view = B[e.ci :, e.ci :]
-        if mode == "fused":
-            new_view, Vbuf, Ts = fused_update(view, b, e.w)
-        else:
-            new_view, Vbuf, Ts = _reduce_block(view, b, e.w, panel_qr_fn, syr2k_update)
+        new_view, Vbuf, Ts = fused_update(B[e.ci :, e.ci :], b, e.w)
         B = B.at[e.ci :, e.ci :].set(new_view)
         Vall = Vall.at[e.ci :, e.panel0 * b : (e.panel0 + e.q) * b].set(Vbuf)
         Tall = Tall.at[e.panel0 : e.panel0 + e.q].set(Ts)

@@ -38,8 +38,6 @@ Both can log their reflectors so Q2 (for eigenvectors) can be applied with
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
-from typing import Optional
 
 import numpy as np
 
@@ -53,7 +51,6 @@ __all__ = [
     "ChaseLog",
     "chase_sequential",
     "chase_wavefront",
-    "chase_wavefront_slices",
     "band_to_tridiag",
     "apply_q2",
     "extract_tridiag",
@@ -191,54 +188,11 @@ def chase_wavefront(B: jax.Array, b: int, return_log: bool = False):
     """Accelerated executor: one batched update per wavefront.
 
     Per wavefront ``w`` the active ops are {(s, w - 3s)}; their windows are
-    gathered with a vmapped dynamic slice, updated in parallel, and scattered
-    back (windows are disjoint by construction; masked slots target a shared
-    zero scratch block and write zeros, which is race-free).
-    """
-    n = B.shape[0]
-    if n < 3 or b <= 1:
-        return chase_sequential(B, b, return_log)
-
-    kmax_np = _kmax_table(n, b)
-    kmax = jnp.asarray(kmax_np)
-    A = max_active_sweeps(n, b)
-    W_total = num_wavefronts(n, b)
-    off, scratch0, _ = _pad_sizes(n, b)
-    w3 = 3 * b
-
-    Bp = _embed(B, b)
-    slot = jnp.arange(A, dtype=jnp.int32)
-
-    def body(Bp, w):
-        s = w // 3 - slot
-        k = w - 3 * s
-        s_safe = jnp.clip(s, 0, n - 3)
-        active = (s >= 0) & (s <= n - 3) & (k >= 0) & (k <= kmax[s_safe])
-        r0 = jnp.where(active, off + s + 1 + (k - 1) * b, scratch0)
-        Ws = jax.vmap(lambda r: lax.dynamic_slice(Bp, (r, r), (w3, w3)))(r0)
-        Wn, vs, taus = jax.vmap(lambda Wi, ki: _window_op(Wi, ki, b))(Ws, k)
-        rows = r0[:, None] + jnp.arange(w3)[None, :]
-        Bp = Bp.at[rows[:, :, None], rows[:, None, :]].set(Wn)
-        row0 = jnp.where(active, s + 1 + k * b, n).astype(jnp.int32)
-        return Bp, (vs, taus, row0)
-
-    Bp, (vs, taus, row0) = lax.scan(body, Bp, jnp.arange(W_total, dtype=jnp.int32))
-    out = lax.dynamic_slice(Bp, (off, off), (n, n))
-    log = ChaseLog(vs=vs, taus=taus, row0=row0, n=n, b=b)
-    return (out, log) if return_log else out
-
-
-def chase_wavefront_slices(B: jax.Array, b: int, return_log: bool = False):
-    """The fused-mode XLA wavefront executor: slice write-back.
-
-    Identical to :func:`chase_wavefront` — same vmapped window gather, same
-    vmapped window op, so the compiled per-window arithmetic is the SAME XLA
-    subgraph and the results are bitwise equal — except the scatter
-    write-back ``Bp.at[rows, rows].set(Wn)`` (an advanced-index scatter XLA
-    lowers to a gather/scatter pair that dominates the whole tridiagonal
-    stage off-TPU) is replaced by a fori loop of ``dynamic_update_slice``
-    writes.  Windows within a wavefront are disjoint, so the sequential
-    write-back commutes and the loop carries no cross-slot dependence.
+    gathered with a vmapped dynamic slice and updated in parallel, then
+    written back by a fori loop of ``dynamic_update_slice`` writes (masked
+    slots target a shared zero scratch block and write zeros).  Windows
+    within a wavefront are disjoint, so the sequential write-back commutes
+    and the loop carries no cross-slot dependence.
     """
     n = B.shape[0]
     if n < 3 or b <= 1:
@@ -276,43 +230,16 @@ def chase_wavefront_slices(B: jax.Array, b: int, return_log: bool = False):
     return (out, log) if return_log else out
 
 
-def band_to_tridiag(
-    B: jax.Array,
-    b: int,
-    *,
-    method: str = "wavefront",
-    return_log: bool = False,
-    mode: Optional[str] = None,
-):
+def band_to_tridiag(B: jax.Array, b: int, *, return_log: bool = False):
     """Reduce a symmetric band matrix (dense storage) to tridiagonal form.
 
-    ``mode`` selects the first-stage pipeline generation (default: the
-    process-wide ``repro.backend.registry.default_tridiag()``, i.e. the
-    ``REPRO_TRIDIAG`` env var or ``"fused"``):
-
-    * ``"fused"``   — the ``bulge_wavefront`` registry op: the grouped
-      wavefront kernel (or its slice-write XLA executor off-TPU), which
-      emits the reflector log directly, so eigenvector runs stay on the
-      fast path too.
-    * ``"unfused"`` — the legacy composition kept as the oracle: the
-      values-only ``bulge_chase`` registry op, scatter-write
-      ``chase_wavefront`` when a log is needed.
+    Runs the ``bulge_wavefront`` registry op: the grouped wavefront kernel
+    (or, off its ceilings, :func:`chase_wavefront`), which emits the
+    reflector log directly, so eigenvector runs stay on the kernel too.
     """
-    if method == "sequential":
-        return chase_sequential(B, b, return_log)
-    if method != "wavefront":
-        raise ValueError(f"unknown bulge chasing method: {method}")
     from repro.backend import registry
 
-    if mode is None:
-        mode = registry.default_tridiag()
-    if mode == "fused":
-        return registry.resolve("bulge_wavefront")(B, b, return_log=return_log)
-    if mode != "unfused":
-        raise ValueError(f"unknown tridiag mode: {mode}")
-    if not return_log:
-        return registry.resolve("bulge_chase")(B, b)
-    return chase_wavefront(B, b, return_log)
+    return registry.resolve("bulge_wavefront")(B, b, return_log=return_log)
 
 
 def extract_tridiag(T: jax.Array) -> tuple[jax.Array, jax.Array]:

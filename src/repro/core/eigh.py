@@ -40,7 +40,6 @@ def _as_config(
     b: Optional[int],
     nb: Optional[int],
     method: str,
-    chase: str = "wavefront",
     max_sweeps: int = 16,
 ) -> EvdConfig:
     if config is not None:
@@ -48,7 +47,7 @@ def _as_config(
             k: v
             for k, v, default in (
                 ("b", b, None), ("nb", nb, None), ("method", method, "two_stage"),
-                ("chase", chase, "wavefront"), ("max_sweeps", max_sweeps, 16),
+                ("max_sweeps", max_sweeps, 16),
             )
             if v != default
         }
@@ -58,7 +57,7 @@ def _as_config(
                 f"it: {overridden}"
             )
         return config
-    return EvdConfig(method=method, chase=chase, b=b, nb=nb, max_sweeps=max_sweeps)
+    return EvdConfig(method=method, b=b, nb=nb, max_sweeps=max_sweeps)
 
 
 def eigh(
@@ -68,7 +67,6 @@ def eigh(
     b: Optional[int] = None,
     nb: Optional[int] = None,
     method: str = "two_stage",
-    chase: str = "wavefront",
     eigenvectors: bool = True,
     max_sweeps: int = 16,
 ):
@@ -78,8 +76,7 @@ def eigh(
     plan API (``repro.solver``) for repeated same-shape solves and
     partial-spectrum selection; this wrapper shares its caches.
     """
-    cfg = _as_config(config, b=b, nb=nb, method=method, chase=chase,
-                     max_sweeps=max_sweeps)
+    cfg = _as_config(config, b=b, nb=nb, method=method, max_sweeps=max_sweeps)
     return plan_for(A, cfg)(A, eigenvectors=eigenvectors)
 
 
@@ -95,7 +92,6 @@ def eigh_batched(
     b: Optional[int] = None,
     nb: Optional[int] = None,
     method: str = "two_stage",
-    chase: str = "wavefront",
     max_sweeps: int = 16,
 ):
     """eigh over a batch of matrices (..., n, n).
@@ -107,8 +103,7 @@ def eigh_batched(
     — or just ``w`` when called with ``eigenvectors=False`` (see also
     :func:`eigvalsh_batched`).
     """
-    cfg = _as_config(config, b=b, nb=nb, method=method, chase=chase,
-                     max_sweeps=max_sweeps)
+    cfg = _as_config(config, b=b, nb=nb, method=method, max_sweeps=max_sweeps)
     return solve_many(A, cfg, eigenvectors=eigenvectors)
 
 

@@ -22,7 +22,6 @@ from .ops import (
     syr2k,
     trailing_update,
     fused_panel_update,
-    bulge_chase,
     bulge_wavefront,
     panel_qr,
     backtransform_wy,
@@ -32,7 +31,6 @@ __all__ = [
     "syr2k",
     "trailing_update",
     "fused_panel_update",
-    "bulge_chase",
     "bulge_wavefront",
     "panel_qr",
     "backtransform_wy",

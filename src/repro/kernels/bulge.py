@@ -80,7 +80,6 @@ from .limits import tile_bytes, vmem_limit_bytes
 
 __all__ = [
     "bulge_wavefront_pallas",
-    "bulge_chase_pallas",
     "bulge_vmem_bytes",
     "chase_slot_counts",
     "bulge_strip_vmem_bytes",
@@ -447,8 +446,3 @@ def bulge_wavefront_pallas(
         vs = res[1][:W_total].reshape(W_total, S * G, b)
         return out, (vs, res[2][:W_total], res[3][:W_total])
     return out
-
-
-def bulge_chase_pallas(B: jax.Array, b: int, *, interpret: bool = False) -> jax.Array:
-    """Values-only alias kept for the original kernel's call sites."""
-    return bulge_wavefront_pallas(B, b, return_log=False, interpret=interpret)

@@ -5,9 +5,10 @@ the first stage's per-block work — q = w/b compensated panel QRs, their
 compact-WY (V, T) factors, the Z = A·V·T intermediates, and the rank-2w
 two-sided SYR2K trailing update — executes as ONE kernel invocation, with
 the panel, V (the paper's W/Y), Z, and T factors VMEM-resident across the
-entire trailing sweep.  The unfused composition writes V/Z/T back to HBM
-after every panel and re-reads them for the trailing syr2k; here they are
-produced and consumed without ever leaving VMEM — the "convert memory-bound
+entire trailing sweep.  Run as separate panel QRs and a syr2k
+(``repro.core.band_reduction._reduce_block``), the block step writes V/Z/T
+back to HBM after every panel and re-reads them for the trailing syr2k;
+here they are produced and consumed without ever leaving VMEM — the "convert memory-bound
 to compute-bound" conversion applied to the whole block step, not just the
 trailing GEMM.
 
@@ -39,7 +40,7 @@ On the TPU the panel rows must sit at multiples of 8 (``b % 8 == 0``) and
 the trailing tiles at multiples of 128 lanes (``w`` and the padded side a
 multiple of 128): :func:`fused_tpu_aligned`.  VMEM is counted by
 :func:`fused_vmem_bytes`; where either check fails the ops wrapper falls
-back to the unfused panel_qr + syr2k composition, which streams and has no
+back to ``_reduce_block``'s panel QRs + syr2k, which stream and have no
 residency limit.
 """
 from __future__ import annotations
@@ -139,7 +140,7 @@ def _fused_kernel(
             Zrow = _dot_nt(E, ZT)
             PT = bv_ref[pl.ds(c0, b), :] - _dot(Vrow, ZT) - _dot(Zrow, VT)
             # --- panel QR of rows [r0, m), fully in VMEM -------------------
-            # LAPACK signs: the unfused oracle composition factors with
+            # LAPACK signs: the _reduce_block oracle factors with
             # panel_qr_geqrf, and parity needs matching reflector signs.
             VhT, T_j, _taus, FT = panel_qr_rows(PT, b, p0=r0, lapack_sign=True)
             # --- exact final column values (band structure) ----------------

@@ -9,8 +9,9 @@ Responsibilities:
   reconstruct the full symmetric result.
 
 Nothing outside ``repro.kernels`` calls ``pl.pallas_call`` directly, and
-nothing outside this package should call these wrappers directly either —
-the framework resolves kernels through ``repro.backend.registry``.
+the pipeline resolves these wrappers through ``repro.backend.registry``
+(``syr2k`` and ``panel_qr`` are reached through ``trailing_update`` and the
+tests).
 """
 from __future__ import annotations
 
@@ -42,7 +43,6 @@ __all__ = [
     "trailing_update",
     "fused_panel_update",
     "fused_uses_kernel",
-    "bulge_chase",
     "bulge_wavefront",
     "bulge_uses_kernel",
     "bulge_kernel",
@@ -114,8 +114,9 @@ def fused_uses_kernel(
     m: int, w: int, b: int, *, bm: int = 128, interpret: Optional[bool] = None
 ) -> bool:
     """Whether :func:`fused_panel_update` on an (m, m) trailing view runs the
-    fused Pallas kernel (True) or the unfused panel_qr + syr2k composition
-    (False).  Single source of truth for the dispatch decision."""
+    fused Pallas kernel (True) or ``_reduce_block``'s panel QRs and syr2k
+    trailing update (False).  Single source of truth for the dispatch
+    decision."""
     explicit = interpret is not None
     interp = probe.interpret_mode() if interpret is None else interpret
     if interp and not explicit:
@@ -138,8 +139,8 @@ def fused_panel_update(
 
     Returns ``(new_view, Vbuf (m, w), Ts (q, b, b))`` with the contract of
     ``repro.core.band_reduction._reduce_block``.  Above the VMEM/interpret
-    ceilings it falls back to the unfused composition on the active
-    backend's trailing update (same math, streamed).
+    ceilings it falls back to ``_reduce_block`` on the active backend's
+    trailing update (same math, streamed).
     """
     m = Bv.shape[0]
     if not fused_uses_kernel(m, w, b, bm=bm, interpret=interpret):
@@ -168,11 +169,9 @@ def bulge_uses_kernel(
     return_log: bool = False,
     interpret: Optional[bool] = None,
 ) -> bool:
-    """Whether the dense-resident kernel runs at size ``n``: for
-    :func:`bulge_chase` the choice between it (True) and the XLA wavefront
-    fallback (False), for :func:`bulge_wavefront` the first choice of
-    :func:`bulge_kernel`.  Benchmarks and diagnostics must use these rather
-    than re-deriving the ceilings.
+    """Whether the dense-resident kernel runs at size ``n``: the first
+    choice of :func:`bulge_kernel`.  Diagnostics must use these rather than
+    re-deriving the ceilings.
     """
     if n < 3 or b <= 1:
         return False
@@ -214,22 +213,6 @@ def _wavefront_group(n: int, b: int) -> int:
     return wavefront_group(n, b)
 
 
-def bulge_chase(B: jax.Array, b: int, *, interpret: Optional[bool] = None) -> jax.Array:
-    """Band -> tridiagonal via the VMEM-resident wavefront kernel; falls back
-    to the XLA wavefront executor above the VMEM ceiling.
-
-    The interpret-mode ceiling applies only when interpretation is implied by
-    the platform; an EXPLICIT ``interpret=True`` (validation of the kernel
-    itself) runs the kernel up to the VMEM ceiling regardless of cost.
-    """
-    if not bulge_uses_kernel(B.shape[0], b, group=1, interpret=interpret):
-        from repro.core.bulge_chasing import chase_wavefront
-
-        return chase_wavefront(B, b)
-    interpret = probe.interpret_mode() if interpret is None else interpret
-    return bulge_wavefront_pallas(B, b, interpret=interpret)
-
-
 def bulge_wavefront(
     B: jax.Array,
     b: int,
@@ -240,22 +223,22 @@ def bulge_wavefront(
 ):
     """Grouped wavefront bulge chase, optionally emitting the reflector log.
 
-    The fused-mode registry op: the kernel chases ``group`` bulges per loop
-    iteration (default: the per-platform ``repro.solver.autotune.wavefront_group``)
-    and can emit the sweep-major ``ChaseLog`` directly, so eigenvector runs
-    stay on the kernel path.  It holds the dense matrix in VMEM where that
-    fits and the band strip where only the strip does
-    (:func:`bulge_kernel`).  Above both VMEM counts or the interpret
-    ceiling — or for trivial sizes — it falls back to the slice-write XLA
-    wavefront executor.
+    The ``bulge_wavefront`` registry op: the kernel chases ``group`` bulges
+    per loop iteration (default: the per-platform
+    ``repro.solver.autotune.wavefront_group``) and can emit the sweep-major
+    ``ChaseLog`` directly, so eigenvector runs stay on the kernel path.  It
+    holds the dense matrix in VMEM where that fits and the band strip where
+    only the strip does (:func:`bulge_kernel`).  Above both VMEM counts or the interpret
+    ceiling — or for trivial sizes — it falls back to the XLA wavefront
+    executor.
     """
     n = B.shape[0]
-    from repro.core.bulge_chasing import ChaseLog, chase_wavefront_slices
+    from repro.core.bulge_chasing import ChaseLog, chase_wavefront
 
     group = _wavefront_group(n, b) if (group is None and n >= 3) else group
     kernel = bulge_kernel(n, b, group=group, return_log=return_log, interpret=interpret)
     if kernel is None:
-        return chase_wavefront_slices(B, b, return_log)
+        return chase_wavefront(B, b, return_log)
     interpret = probe.interpret_mode() if interpret is None else interpret
     kw = dict(group=int(group), strip=kernel == BULGE_STRIP, interpret=interpret)
     if not return_log:
@@ -313,9 +296,8 @@ def backtransform_wy(
     """Blocked Q2 back-transform via the VMEM-resident kernel; falls back to
     the XLA scan implementation above the VMEM/interpret ceilings.
 
-    As with :func:`bulge_chase`, an EXPLICIT ``interpret=True`` (validating
-    the kernel itself) runs the kernel regardless of the implied-interpret
-    size ceiling.
+    An EXPLICIT ``interpret=True`` (validating the kernel itself) runs the
+    kernel regardless of the implied-interpret size ceiling.
     """
     n, m = X.shape
     if not backtransform_uses_kernel(n, m, b, group=group, interpret=interpret):

@@ -45,7 +45,7 @@ def panel_qr_rows(PT: jax.Array, b: int, *, p0=0, lapack_sign: bool = False):
     +|x|, this kernel's historical convention); with ``lapack_sign=True``
     they follow LAPACK ``larfg`` / ``panel_qr_geqrf`` (beta =
     -sign(alpha)·|x|), which the fused first-stage kernel uses so its output
-    is comparable to the geqrf-based unfused composition.
+    is comparable to the geqrf-based ``_reduce_block``.
     """
     m = PT.shape[1]
     dtype = PT.dtype

@@ -17,7 +17,6 @@ __all__ = [
     "symm_ref",
     "panel_qr_ref",
     "bulge_sweep_ref",
-    "bulge_wavefront_ref",
 ]
 
 
@@ -39,11 +38,10 @@ def trailing_update_ref(C: jax.Array, Y: jax.Array, Z: jax.Array) -> jax.Array:
 
 
 def fused_panel_update_ref(Bv: jax.Array, b: int, w: int):
-    """Oracle for the fused panel+trailing kernel: the unfused composition.
+    """Oracle for the fused panel+trailing kernel: ``_reduce_block``.
 
-    Literally the legacy block step — geqrf panel QRs + the jnp trailing
-    update — so the fused jnp registry path is BITWISE the unfused jnp path
-    (same XLA subgraph), and the Pallas kernel is tested against it allclose.
+    geqrf panel QRs + the jnp trailing update — the jnp registry op, which
+    the Pallas kernel is tested against allclose.
     Returns ``(new_view, Vbuf (m, w), Ts (w//b, b, b))``.
     """
     from repro.core.band_reduction import _reduce_block
@@ -69,11 +67,3 @@ def bulge_sweep_ref(B: jax.Array, b: int):
     from repro.core.bulge_chasing import chase_sequential
 
     return chase_sequential(B, b)
-
-
-def bulge_wavefront_ref(B: jax.Array, b: int, *, return_log: bool = False):
-    """Oracle for the grouped-wavefront kernel: the scatter-write wavefront
-    executor (the legacy accelerated schedule — same ops, same order)."""
-    from repro.core.bulge_chasing import chase_wavefront
-
-    return chase_wavefront(B, b, return_log)

@@ -9,7 +9,7 @@ the batched front door owns the plan cache, the batch padding, and (when
 ``precond_mesh`` is set) the shard_map routing, so this file carries no
 bespoke padding/sharding plumbing.  All solver tuning flows through ONE
 field: ``ShampooOptions.evd`` is a frozen :class:`repro.solver.EvdConfig`
-(method, chase, blocking, kernel-backend pin).
+(method, blocking, kernel-backend pin).
 
 Layout: every eligible parameter is cut into (block, block) tiles; all tiles
 across the whole model are stacked into ONE (NB, bs, bs) batch so the solver

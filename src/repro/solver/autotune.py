@@ -99,7 +99,6 @@ _WAVEFRONT_GROUP_TABLE = {
 # registry's pallas wrappers call back into tile_defaults below).
 _TILE_TABLE = {
     "tpu": {
-        "syr2k": dict(bm=256, bk=256),
         "trailing_update": dict(bm=256, bk=256),
         # Trailing tile of the fused panel+trailing kernel.  Smaller than
         # the standalone syr2k tile: the resident factor buffers (V/Z/F at
@@ -107,7 +106,6 @@ _TILE_TABLE = {
         "fused_panel_update": dict(bm=128),
     },
     None: {  # interpret mode: small tiles keep emulated grids cheap
-        "syr2k": dict(bm=128, bk=128),
         "trailing_update": dict(bm=128, bk=128),
         "fused_panel_update": dict(bm=64),
     },

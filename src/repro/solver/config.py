@@ -1,8 +1,8 @@
 """Solver configuration: the plan/execute split's *what* half.
 
 ``EvdConfig`` is a frozen, hashable description of HOW an EVD should be
-computed (method, chase schedule, blocking policy, kernel backend,
-tolerance, spectrum selection).  It deliberately contains no shapes: the
+computed (method, blocking policy, kernel backend, tolerance, spectrum
+selection).  It deliberately contains no shapes: the
 same config can plan solvers for many (n, dtype) pairs.  ``Spectrum``
 selects WHICH part of the spectrum to compute — vendor libraries (cuSOLVER
 syevdx, LAPACK ``RANGE='I'``) and Keyes et al. 2021 treat partial-spectrum
@@ -20,9 +20,6 @@ from typing import Optional
 __all__ = ["Spectrum", "EvdConfig", "full_spectrum", "by_index", "by_count"]
 
 METHODS = ("two_stage", "direct", "jacobi")
-CHASES = ("wavefront", "sequential")
-BACKTRANSFORMS = ("blocked", "scan")
-TRIDIAGS = ("fused", "unfused")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -93,18 +90,6 @@ class EvdConfig:
 
     * ``method``  — ``two_stage`` (the paper), ``direct`` (one-stage
       Householder baseline), ``jacobi`` (dense parallel Jacobi).
-    * ``chase``   — bulge-chase schedule: ``wavefront`` | ``sequential``.
-    * ``backtransform`` — eigenvector back-transform path: ``blocked``
-      (default; compact-WY GEMM aggregation of Q1 and Q2 — see
-      ``repro.core.backtransform``) | ``scan`` (the per-reflector appliers,
-      kept as the numerical/ordering oracle).  Two-stage only; the direct
-      and Jacobi methods ignore it.
-    * ``tridiag`` — first-stage pipeline generation: ``fused`` (band
-      reduction as fused panel+trailing ops, grouped-wavefront bulge chase)
-      | ``unfused`` (the legacy panel_qr + syr2k composition and
-      scatter-write chase, kept as the oracle).  ``None`` = the process
-      default (``REPRO_TRIDIAG`` env var, else ``fused``), resolved at plan
-      time like ``backend``.  Two-stage only.
     * ``b, nb``   — bandwidth / update block.  ``None`` = resolved from the
       per-platform autotuning table at plan time (repro.solver.autotune).
     * ``backend`` — kernel-registry backend pin (``pallas`` | ``jnp`` | a
@@ -116,9 +101,6 @@ class EvdConfig:
     """
 
     method: str = "two_stage"
-    chase: str = "wavefront"
-    backtransform: str = "blocked"
-    tridiag: Optional[str] = None
     b: Optional[int] = None
     nb: Optional[int] = None
     backend: Optional[str] = None
@@ -129,17 +111,6 @@ class EvdConfig:
     def __post_init__(self):
         if self.method not in METHODS:
             raise ValueError(f"unknown method {self.method!r}; expected one of {METHODS}")
-        if self.chase not in CHASES:
-            raise ValueError(f"unknown chase {self.chase!r}; expected one of {CHASES}")
-        if self.backtransform not in BACKTRANSFORMS:
-            raise ValueError(
-                f"unknown backtransform {self.backtransform!r}; expected one "
-                f"of {BACKTRANSFORMS}"
-            )
-        if self.tridiag is not None and self.tridiag not in TRIDIAGS:
-            raise ValueError(
-                f"unknown tridiag {self.tridiag!r}; expected one of {TRIDIAGS}"
-            )
         if self.b is not None and self.b < 1:
             raise ValueError(f"b must be >= 1, got {self.b}")
         if self.nb is not None and self.nb < 1:

@@ -70,11 +70,9 @@ class _Deps:
 
             class _M:
                 band_reduce = staticmethod(band_reduction.band_reduce)
-                apply_q_left = staticmethod(band_reduction.apply_q_left)
                 apply_q_left_blocked = staticmethod(bt.apply_q_left_blocked)
                 apply_q2_blocked = staticmethod(bt.apply_q2_blocked)
                 band_to_tridiag = staticmethod(bulge_chasing.band_to_tridiag)
-                apply_q2 = staticmethod(bulge_chasing.apply_q2)
                 extract_tridiag = staticmethod(bulge_chasing.extract_tridiag)
                 direct_tridiagonalize = staticmethod(direct_tridiag.direct_tridiagonalize)
                 apply_q_direct = staticmethod(direct_tridiag.apply_q_direct)
@@ -112,20 +110,15 @@ def _end_stage(stage: str, outs):
     return outs
 
 
-def _tridiag_pipeline(
-    A, *, b, nb, method, chase, return_reflectors=False, merge_reflectors=False,
-    tridiag=None,
-):
-    """Reduce symmetric A to tridiagonal (d, e) via the requested pipeline.
+def _tridiag_pipeline(A, *, b, nb, method, return_reflectors=False):
+    """Reduce symmetric A to tridiagonal (d, e).
 
-    ``tridiag`` selects the first-stage generation ("fused" | "unfused" |
-    None = process default); both generations emit identical
-    ``BandReflectors``/``ChaseLog`` structures, so everything downstream
-    (bisection, inverse iteration, back-transform) is mode-oblivious.
-
-    The two-stage method runs its stages under ``jax.named_scope``
-    ``evd.first_stage`` and ``evd.bulge_chase`` and marks the end of each
-    (:func:`_end_stage`); the direct method is one stage of its caller's.
+    With ``return_reflectors`` the two-stage method also returns the
+    T-merged Q1 reflectors and the chase log that the blocked
+    back-transform applies.  The two-stage method runs its stages under
+    ``jax.named_scope`` ``evd.first_stage`` and ``evd.bulge_chase`` and
+    marks the end of each (:func:`_end_stage`); the direct method is one
+    stage of its caller's.
     """
     if method == "direct":
         T, refl = _deps.direct_tridiagonalize(A, return_reflectors=True)
@@ -135,38 +128,33 @@ def _tridiag_pipeline(
         return d, e
 
     if not return_reflectors:
-        # Values-only fast path: no reflector log, so the bulge chase can
-        # dispatch to the VMEM-resident Pallas kernel via the registry.
         with jax.named_scope("evd.first_stage"):
-            Bband = _deps.band_reduce(A, b, nb, mode=tridiag)
+            Bband = _deps.band_reduce(A, b, nb)
         Bband = _end_stage("first_stage", Bband)
         with jax.named_scope("evd.bulge_chase"):
-            T = _deps.band_to_tridiag(Bband, b, method=chase, mode=tridiag)
+            T = _deps.band_to_tridiag(Bband, b)
             d, e = _deps.extract_tridiag(T)
         return _end_stage("bulge_chase", (d, e))
 
     with jax.named_scope("evd.first_stage"):
         Bband, refl1 = _deps.band_reduce(
-            A, b, nb, return_reflectors=True, merge_ts=merge_reflectors, mode=tridiag
+            A, b, nb, return_reflectors=True, merge_ts=True
         )
     Bband, refl1 = _end_stage("first_stage", (Bband, refl1))
     with jax.named_scope("evd.bulge_chase"):
-        T, log2 = _deps.band_to_tridiag(
-            Bband, b, method=chase, return_log=True, mode=tridiag
-        )
+        T, log2 = _deps.band_to_tridiag(Bband, b, return_log=True)
         d, e = _deps.extract_tridiag(T)
     d, e, refl1, log2 = _end_stage("bulge_chase", (d, e, refl1, log2))
     return d, e, ("two_stage", (refl1, log2))
 
 
-def _backtransform(kind_refl, X: jax.Array, carry=(), *, mode: str = "scan", group: int = 0):
+def _backtransform(kind_refl, X: jax.Array, carry=(), *, group: int = 0):
     """x_A = Q x_T where Q is the accumulated tridiagonalization transform.
 
-    ``mode`` selects the eigenvector back-transform path: ``blocked`` runs
-    the compact-WY GEMM subsystem (``repro.core.backtransform`` — Q2 through
-    the registry ``backtransform_wy`` op with WY group size ``group``, Q1
-    through the per-block T-merged appliers); ``scan`` runs the per-reflector
-    oracle appliers.  The two-stage Q2 and Q1 run under ``jax.named_scope``
+    The two-stage transform runs the compact-WY GEMM subsystem
+    (``repro.core.backtransform``): Q2 through the registry
+    ``backtransform_wy`` op with WY group size ``group``, Q1 through the
+    per-block T-merged appliers.  Q2 and Q1 run under ``jax.named_scope``
     ``evd.backtransform_q2`` / ``evd.backtransform_q1``, each end marked;
     ``carry`` passes through the marks with ``X``, so that nothing that
     reads it runs before the last.  Returns ``(X, carry)``.
@@ -176,16 +164,10 @@ def _backtransform(kind_refl, X: jax.Array, carry=(), *, mode: str = "scan", gro
         return _deps.apply_q_direct(refl, X, transpose=False), carry
     refl1, log2 = refl
     with jax.named_scope("evd.backtransform_q2"):
-        if mode == "blocked":
-            X = _deps.apply_q2_blocked(log2, X, transpose=False, group=group or None)
-        else:
-            X = _deps.apply_q2(log2, X, transpose=False)        # Q2 @ X
+        X = _deps.apply_q2_blocked(log2, X, transpose=False, group=group or None)
     X, refl1, carry = _end_stage("backtransform_q2", (X, refl1, carry))
     with jax.named_scope("evd.backtransform_q1"):
-        if mode == "blocked":
-            X = _deps.apply_q_left_blocked(refl1, X, transpose=False)
-        else:
-            X = _deps.apply_q_left(refl1, X, transpose=False)   # Q1 @ (Q2 @ X)
+        X = _deps.apply_q_left_blocked(refl1, X, transpose=False)
     return _end_stage("backtransform_q1", (X, carry))
 
 
@@ -195,7 +177,6 @@ def tridiagonalize(
     b: Optional[int] = None,
     nb: Optional[int] = None,
     method: str = "two_stage",
-    chase: str = "wavefront",
     return_reflectors: bool = False,
 ):
     """Symmetric A -> (d, e) tridiagonal, optionally with back-transform data.
@@ -206,16 +187,14 @@ def tridiagonalize(
     n = A.shape[0]
     if method == "direct":
         return _tridiag_pipeline(
-            A, b=1, nb=1, method="direct", chase=chase,
-            return_reflectors=return_reflectors,
+            A, b=1, nb=1, method="direct", return_reflectors=return_reflectors
         )
     if method != "two_stage":
         raise ValueError(f"unknown tridiagonalization method: {method}")
     dec = resolve_blocking(n, b=b, nb=nb)
     eff = "direct" if dec.b <= 1 else "two_stage"
     return _tridiag_pipeline(
-        A, b=dec.b, nb=dec.nb, method=eff, chase=chase,
-        return_reflectors=return_reflectors,
+        A, b=dec.b, nb=dec.nb, method=eff, return_reflectors=return_reflectors
     )
 
 
@@ -245,7 +224,7 @@ class StagePath:
         return out
 
 
-def _stage_paths(n, b, nb, k, *, config, tridiag, platform, bt_group):
+def _stage_paths(n, b, nb, k, *, platform, bt_group):
     """The kernel-versus-XLA record of a two-stage plan on the Pallas backend."""
     from repro.core.backtransform import _sweep_shape
     from repro.core.band_reduction import build_stage_schedule
@@ -254,22 +233,16 @@ def _stage_paths(n, b, nb, k, *, config, tridiag, platform, bt_group):
     bm = tile_defaults("fused_panel_update", platform)["bm"]
     first: Dict[str, list] = {}
     for e in build_stage_schedule(n, b, nb).entries:
-        fused = tridiag == "fused" and ops.fused_uses_kernel(e.m, e.w, b, bm=bm)
+        fused = ops.fused_uses_kernel(e.m, e.w, b, bm=bm)
         first.setdefault("fused_panel_update" if fused else "syr2k_lower", []).append(e.m)
     paths = [StagePath("first_stage", kern, tuple(ms)) for kern, ms in first.items()]
 
     for vectors in (False, True):
-        if config.chase != "wavefront" or (tridiag == "unfused" and vectors):
-            kernel = None
-        elif tridiag == "unfused":
-            kernel = ops.BULGE_DENSE if ops.bulge_uses_kernel(n, b, group=1) else None
-        else:
-            kernel = ops.bulge_kernel(n, b, return_log=vectors)
+        kernel = ops.bulge_kernel(n, b, return_log=vectors)
         paths.append(StagePath("bulge_chase", kernel or XLA, eigenvectors=vectors))
 
     kernel = (
-        config.backtransform == "blocked"
-        and 0 not in _sweep_shape(n, b)
+        0 not in _sweep_shape(n, b)
         and ops.backtransform_uses_kernel(n, k, b, group=bt_group or None)
     )
     paths.append(StagePath(
@@ -298,7 +271,6 @@ class EvdPlan:
     fallback_reason: Optional[str] = None
     bt_group: int = 0                # blocked back-transform WY group size G
                                      # (0: back-transform not applicable)
-    tridiag: str = "fused"           # resolved first-stage pipeline generation
     paths: Tuple[StagePath, ...] = ()  # kernel-versus-XLA record (Pallas
                                        # backend, two-stage method only)
 
@@ -357,10 +329,8 @@ class EvdPlan:
         parts = [
             f"EvdPlan(n={self.n}, {self.dtype}, method={self.method}, "
             f"b={self.b}, nb={self.nb}, backend={self.backend}, "
-            f"platform={self.platform}, k={self.k}/{self.n}, "
-            f"tridiag={self.tridiag}, "
-            f"backtransform={self.config.backtransform}"
-            + (f"[G={self.bt_group}]" if self.bt_group else "")
+            f"platform={self.platform}, k={self.k}/{self.n}"
+            + (f", backtransform G={self.bt_group}" if self.bt_group else "")
             + ")"
         ]
         if self.fallback_reason:
@@ -384,10 +354,7 @@ class EvdPlan:
         ``(W, S*G)`` rectangle, and the slots it visits."""
         from repro.kernels.bulge import chase_slot_counts
 
-        # The unfused pipeline's values-only chase runs one bulge a group.
-        group = 1 if self.tridiag == "unfused" else wavefront_group(
-            self.n, self.b, self.platform
-        )
+        group = wavefront_group(self.n, self.b, self.platform)
         live, visited, rect = chase_slot_counts(self.n, self.b, group)
         return (
             f"  bulge_chase slots: {live} of {rect} "
@@ -427,11 +394,7 @@ def plan(n: int, dtype, config: EvdConfig = EvdConfig()) -> EvdPlan:
         backend = registry.default_backend()
     else:
         backend = registry.validate_backend(config.backend)
-    # None = process default, resolved NOW (like backend) so the env knob is
-    # part of the cache key rather than a silent trace-time dependency.
-    tridiag = config.tridiag or registry.default_tridiag()
-
-    key = (n, dtype_name, config, backend, platform, tridiag)
+    key = (n, dtype_name, config, backend, platform)
     cached = _PLAN_CACHE.get(key)
     if cached is not None:
         return cached
@@ -443,13 +406,13 @@ def plan(n: int, dtype, config: EvdConfig = EvdConfig()) -> EvdPlan:
     else:
         b, nb, reason = 0, 0, None
     bt_group = 0
-    if config.method == "two_stage" and b > 1 and config.backtransform == "blocked":
+    if config.method == "two_stage" and b > 1:
         bt_group = backtransform_group(n, b, platform)
     paths = ()
     if config.method == "two_stage" and b > 1 and backend == "pallas":
         paths = _stage_paths(
-            n, b, nb, config.spectrum.index_range(n)[1], config=config,
-            tridiag=tridiag, platform=platform, bt_group=bt_group,
+            n, b, nb, config.spectrum.index_range(n)[1],
+            platform=platform, bt_group=bt_group,
         )
 
     pl = EvdPlan(
@@ -463,7 +426,6 @@ def plan(n: int, dtype, config: EvdConfig = EvdConfig()) -> EvdPlan:
         platform=platform,
         fallback_reason=reason,
         bt_group=bt_group,
-        tridiag=tridiag,
         paths=paths,
     )
     _PLAN_CACHE[key] = pl
@@ -537,11 +499,8 @@ def _solve(A: jax.Array, *, pl: EvdPlan, eigenvectors: bool, staged: bool):
     def mark(name, outs):
         return _end_stage(name, outs) if staged else outs
 
-    mode = pl.config.backtransform if pl.method == "two_stage" else "scan"
     d, e, *refl = _tridiag_pipeline(
-        A, b=pl.b, nb=pl.nb, method=pl.method, chase=pl.config.chase,
-        return_reflectors=eigenvectors, merge_reflectors=mode == "blocked",
-        tridiag=pl.tridiag,
+        A, b=pl.b, nb=pl.nb, method=pl.method, return_reflectors=eigenvectors
     )
     with scope("bisection"):
         w = _deps.eigvalsh_tridiag_range(
@@ -557,7 +516,7 @@ def _solve(A: jax.Array, *, pl: EvdPlan, eigenvectors: bool, staged: bool):
     with scope("inverse_iteration"):
         VT = _deps.eigvecs_inverse_iteration(d, e, w)
     VT, w, refl = mark("inverse_iteration", (VT, w, refl))
-    V, w = _backtransform((kind, refl), VT, w, mode=mode, group=pl.bt_group)
+    V, w = _backtransform((kind, refl), VT, w, group=pl.bt_group)
     return w, V
 
 

@@ -4,8 +4,8 @@ Covers the acceptance contract of the backend subsystem:
 * every (op, backend) pair resolves and the pallas/jnp pairs agree
   numerically;
 * ``eigh(A, method="two_stage")`` executes the Pallas fused first-stage op
-  via the registry by default (``REPRO_TRIDIAG=unfused`` routes the legacy
-  panel_qr + trailing_update composition instead);
+  via the registry by default, and its block steps above the fused
+  kernel's ceiling go through the registry ``trailing_update``;
 * ``REPRO_KERNEL_BACKEND=jnp`` (and the programmatic overrides) force the
   reference path.
 """
@@ -17,6 +17,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.backend import cache, compat, registry
+from repro.core.panel_qr import panel_qr_geqrf
+from repro.kernels import ops
+from repro.kernels import ref as kref
 from conftest import random_symmetric
 
 
@@ -32,8 +35,9 @@ def test_resolve_never_degrades_to_jnp(monkeypatch):
     # No capability probe stands between the default and the kernels: with
     # no override, resolution lands on the Pallas implementation.
     monkeypatch.delenv(registry.ENV_VAR, raising=False)
-    assert registry.resolve("syr2k") is registry.resolve("syr2k", "pallas")
-    assert registry.resolve("syr2k") is not registry.resolve("syr2k", "jnp")
+    op = "trailing_update"
+    assert registry.resolve(op) is registry.resolve(op, "pallas")
+    assert registry.resolve(op) is not registry.resolve(op, "jnp")
 
 
 def test_env_var_overrides_default(monkeypatch):
@@ -60,14 +64,22 @@ def test_use_backend_scopes_and_restores(monkeypatch):
 def test_resolve_rejects_unknown():
     with pytest.raises(KeyError):
         registry.resolve("not_an_op")
+    # The pipeline's five ops; the kernel wrappers behind them are no ops.
+    assert registry.OPS == (
+        "trailing_update", "fused_panel_update", "bulge_wavefront",
+        "backtransform_wy", "stage_mark",
+    )
+    for op in ("syr2k", "panel_qr", "bulge_chase"):
+        with pytest.raises(KeyError):
+            registry.resolve(op)
     with pytest.raises(ValueError):
-        registry.resolve("syr2k", "cuda")
+        registry.resolve("trailing_update", "cuda")
 
 
 def test_tile_defaults_per_platform():
-    assert registry.tile_defaults("syr2k", "tpu")["bm"] == 256
-    assert registry.tile_defaults("syr2k", "cpu")["bm"] == 128
-    assert registry.tile_defaults("bulge_chase") == {}
+    assert registry.tile_defaults("trailing_update", "tpu")["bm"] == 256
+    assert registry.tile_defaults("trailing_update", "cpu")["bm"] == 128
+    assert registry.tile_defaults("bulge_wavefront") == {}
 
 
 # ----------------------------------------------------------- kernel parity
@@ -87,8 +99,8 @@ def test_trailing_update_parity(rng, n, k):
 def test_syr2k_parity(rng, n, k):
     A = jnp.asarray(rng.normal(size=(n, k)).astype(np.float32))
     B = jnp.asarray(rng.normal(size=(n, k)).astype(np.float32))
-    out_p = registry.resolve("syr2k", "pallas")(A, B)
-    out_j = registry.resolve("syr2k", "jnp")(A, B)
+    out_p = ops.syr2k(A, B)
+    out_j = kref.syr2k_ref(A, B)
     np.testing.assert_allclose(
         out_p, out_j, atol=1e-5 * float(jnp.abs(out_j).max() + 1.0)
     )
@@ -100,8 +112,8 @@ def test_bulge_chase_parity(rng, n, b):
 
     A = jnp.asarray(random_symmetric(rng, n))
     Bband = band_reduce(A, b, min(2 * b, n - b))
-    T_p = registry.resolve("bulge_chase", "pallas")(Bband, b)
-    T_j = registry.resolve("bulge_chase", "jnp")(Bband, b)
+    T_p = registry.resolve("bulge_wavefront", "pallas")(Bband, b)
+    T_j = registry.resolve("bulge_wavefront", "jnp")(Bband, b)
     scale = float(jnp.abs(Bband).max())
     # Different op interleavings: compare the invariant (the spectrum) tight,
     # entries loose.
@@ -120,8 +132,8 @@ def test_bulge_chase_parity(rng, n, b):
 @pytest.mark.parametrize("m,b", [(24, 4), (32, 8)])
 def test_panel_qr_parity(rng, m, b):
     P = jnp.asarray(rng.normal(size=(m, b)).astype(np.float32))
-    V1, T1, tau1, R1 = registry.resolve("panel_qr", "pallas")(P)
-    V2, T2, tau2, R2 = registry.resolve("panel_qr", "jnp")(P)
+    V1, T1, tau1, R1 = ops.panel_qr(P)
+    V2, T2, tau2, R2 = panel_qr_geqrf(P)
     # geqrf and the kernel may differ in column-sign convention; the applied
     # orthogonal factor must match up to the signs of R's diagonal.
     Q1 = np.asarray(jnp.eye(m) - V1 @ T1 @ V1.T)
@@ -151,7 +163,6 @@ def test_eigh_two_stage_resolves_pallas_by_default(rng, monkeypatch):
     from repro.core import eigh
 
     monkeypatch.delenv(registry.ENV_VAR, raising=False)
-    monkeypatch.delenv(registry.TRIDIAG_ENV_VAR, raising=False)
     spy = _spy_impl(monkeypatch, "fused_panel_update", "pallas")
     # Unique (shape, blocking) so the jit cache cannot satisfy this call
     # without re-tracing through the registry.
@@ -164,27 +175,32 @@ def test_eigh_two_stage_resolves_pallas_by_default(rng, monkeypatch):
 
 
 def test_unfused_mode_routes_trailing_update(rng, monkeypatch):
-    # The legacy composition stays reachable as the oracle: pinning
-    # REPRO_TRIDIAG=unfused must route panel_qr + trailing_update again.
+    # Above FUSED_PANEL_INTERPRET_MAX_M a two-stage solve runs its block
+    # steps as panel QRs + the registry trailing_update; the fused kernel
+    # takes the blocks under it.
+    import scipy.linalg as sla
+
     from repro.core import eigh
 
     monkeypatch.delenv(registry.ENV_VAR, raising=False)
-    monkeypatch.setenv(registry.TRIDIAG_ENV_VAR, "unfused")
+    monkeypatch.setenv("REPRO_FUSED_PANEL_INTERPRET_MAX_M", "32")
     spy_trailing = _spy_impl(monkeypatch, "trailing_update", "pallas")
     spy_fused = _spy_impl(monkeypatch, "fused_panel_update", "pallas")
-    n = 52
+    n = 52  # block steps on trailing views m = 52, 36 (streamed) and 20 (kernel)
     A = jnp.asarray(random_symmetric(rng, n))
     w = eigh(A, method="two_stage", b=4, nb=16, eigenvectors=False)
-    assert spy_trailing["n"] > 0, "unfused mode skipped the trailing update"
-    assert spy_fused["n"] == 0
-    assert w.shape == (n,)
+    assert spy_fused["n"] == 3
+    assert spy_trailing["n"] == 2, "the streamed block steps skipped trailing_update"
+    w_ref = np.sort(sla.eigvalsh(np.asarray(A, np.float64)))
+    np.testing.assert_allclose(
+        np.sort(np.asarray(w)), w_ref, atol=3e-4 * np.abs(w_ref).max()
+    )
 
 
 def test_env_var_forces_jnp_fallback(rng, monkeypatch):
     from repro.core import eigh
 
     monkeypatch.setenv(registry.ENV_VAR, "jnp")
-    monkeypatch.delenv(registry.TRIDIAG_ENV_VAR, raising=False)
     spy_pallas = _spy_impl(monkeypatch, "fused_panel_update", "pallas")
     spy_jnp = _spy_impl(monkeypatch, "fused_panel_update", "jnp")
     n = 44
@@ -206,7 +222,6 @@ def test_backend_override_beats_jit_cache(rng, monkeypatch):
     from repro.core import eigh
 
     monkeypatch.delenv(registry.ENV_VAR, raising=False)
-    monkeypatch.delenv(registry.TRIDIAG_ENV_VAR, raising=False)
     n = 36
     A = jnp.asarray(random_symmetric(rng, n))
     w1 = eigh(A, b=4, nb=16, eigenvectors=False)  # traces the pallas path

@@ -20,8 +20,11 @@ from repro.core import (
     apply_q_left_blocked,
     band_reduce,
     band_to_tridiag,
+    chase_sequential,
+    eigvecs_inverse_iteration,
     merge_band_reflectors,
     sweep_major_log,
+    tridiagonalize,
 )
 from repro.core.backtransform import backtransform_wy_xla, sweep_group_count
 from repro.solver import EvdConfig, batch_plan, by_count, plan
@@ -29,11 +32,22 @@ from repro.solver.autotune import backtransform_group
 from conftest import random_symmetric
 
 
-def _band_and_log(rng, n, b, nb, chase="wavefront"):
+def _band_and_log(rng, n, b, nb, sequential=False):
     A = jnp.asarray(random_symmetric(rng, n))
     B, refl = band_reduce(A, b, nb, return_reflectors=True, merge_ts=True)
-    T, log = band_to_tridiag(B, b, method=chase, return_log=True)
+    if sequential:
+        T, log = chase_sequential(B, b, return_log=True)
+    else:
+        T, log = band_to_tridiag(B, b, return_log=True)
     return A, refl, log
+
+
+def _scan_vectors(A, b, nb, w):
+    """The plan's first stage, inverse iteration at ``w`` and the scan
+    appliers (the ordering oracle): Q1 (Q2 V_T) reflector by reflector."""
+    d, e, (_, (refl1, log2)) = tridiagonalize(A, b=b, nb=nb, return_reflectors=True)
+    VT = eigvecs_inverse_iteration(d, e, w)
+    return apply_q_left(refl1, apply_q2(log2, VT))
 
 
 # ------------------------------------------------------------------ Q1 merge
@@ -74,7 +88,7 @@ def test_merge_band_reflectors_idempotent_and_validates(rng):
 @pytest.mark.parametrize("chase", ["wavefront", "sequential"])
 @pytest.mark.parametrize("n,b", [(32, 8), (48, 4), (40, 2)])
 def test_q2_blocked_matches_scan(rng, n, b, chase):
-    _, _, log = _band_and_log(rng, n, b, b, chase=chase)
+    _, _, log = _band_and_log(rng, n, b, b, sequential=chase == "sequential")
     X = jnp.asarray(rng.normal(size=(n, 6)).astype(np.float32))
     for transpose in (False, True):
         Z_scan = apply_q2(log, X, transpose=transpose)
@@ -129,19 +143,14 @@ def test_pallas_kernel_explicit_interpret_grouped(rng):
 
 
 # ------------------------------------------------------------ plan threading
-def test_config_validates_backtransform():
-    with pytest.raises(ValueError, match="backtransform"):
-        EvdConfig(backtransform="bogus")
-    assert EvdConfig().backtransform == "blocked"
-
-
 def test_plan_resolves_group_and_caches_separately():
     pb = plan(64, jnp.float32, EvdConfig(b=8, nb=32))
-    ps = plan(64, jnp.float32, EvdConfig(b=8, nb=32, backtransform="scan"))
-    assert pb is not ps
+    p4 = plan(64, jnp.float32, EvdConfig(b=4, nb=32))
+    assert pb is not p4
     assert pb.bt_group == backtransform_group(64, 8) > 0
-    assert ps.bt_group == 0
-    assert "blocked" in pb.describe() and "scan" in ps.describe()
+    assert p4.bt_group == backtransform_group(64, 4) > 0
+    assert f"G={pb.bt_group}" in pb.describe()
+    assert plan(64, jnp.float32, EvdConfig(method="direct")).bt_group == 0
 
 
 @pytest.mark.parametrize("n", [24, 64])
@@ -149,8 +158,7 @@ def test_eigh_blocked_vs_scan_parity(rng, n):
     A = jnp.asarray(random_symmetric(rng, n))
     cfg = dict(b=8, nb=min(32, n // 2))
     wb, Vb = plan(n, jnp.float32, EvdConfig(**cfg))(A)
-    ws, Vs = plan(n, jnp.float32, EvdConfig(backtransform="scan", **cfg))(A)
-    np.testing.assert_allclose(np.asarray(wb), np.asarray(ws), atol=1e-5)
+    Vs = _scan_vectors(A, cfg["b"], cfg["nb"], wb)
     np.testing.assert_allclose(np.asarray(Vb), np.asarray(Vs), atol=1e-4)
     # Eigen-residual + orthogonality on the blocked default.
     scale = max(float(jnp.abs(wb).max()), 1.0)
@@ -164,15 +172,12 @@ def test_partial_spectrum_blocked(rng):
     n, k = 64, 6
     A = jnp.asarray(random_symmetric(rng, n))
     pl = plan(n, jnp.float32, EvdConfig(b=8, nb=32, spectrum=by_count(k)))
-    assert pl.config.backtransform == "blocked"
+    assert pl.bt_group > 0
     w, V = pl(A)
     assert V.shape == (n, k)
     scale = max(float(jnp.abs(w).max()), 1.0)
     assert float(jnp.abs(A @ V - V * w[None, :]).max()) < 1e-4 * scale
-    w_scan, V_scan = plan(
-        n, jnp.float32,
-        EvdConfig(b=8, nb=32, spectrum=by_count(k), backtransform="scan"),
-    )(A)
+    V_scan = _scan_vectors(A, 8, 32, w)
     np.testing.assert_allclose(np.asarray(V), np.asarray(V_scan), atol=1e-4)
 
 

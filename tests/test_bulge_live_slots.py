@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import scipy.linalg as sla
 
 from repro.core import apply_q2, chase_wavefront, extract_tridiag
-from repro.core.bulge_chasing import ChaseLog, chase_wavefront_slices, max_active_sweeps, num_wavefronts
+from repro.core.bulge_chasing import ChaseLog, max_active_sweeps, num_wavefronts
 from repro.kernels.bulge import _live_slots, bulge_wavefront_pallas, chase_slot_counts
 from repro.solver import EvdConfig, plan
 from repro.solver.autotune import wavefront_group
@@ -87,7 +87,7 @@ def test_kernel_log_holds_the_live_slots_alone(n, group, strip):
 
     # The live slots log the executor's reflectors: the same supports, and
     # with the kernel's tridiagonal they rebuild the band.
-    _, log_x = chase_wavefront_slices(Bb, B8, return_log=True)
+    _, log_x = chase_wavefront(Bb, B8, return_log=True)
     np.testing.assert_array_equal(row0[:, :A], np.asarray(log_x.row0))
     d, e = extract_tridiag(T)
     Tt = jnp.diag(d) + jnp.diag(e, -1) + jnp.diag(e, 1)

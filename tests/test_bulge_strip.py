@@ -12,8 +12,7 @@ import jax
 import jax.numpy as jnp
 import scipy.linalg as sla
 
-from repro.core import apply_q2, chase_sequential, extract_tridiag
-from repro.core.bulge_chasing import chase_wavefront_slices
+from repro.core import apply_q2, chase_sequential, chase_wavefront, extract_tridiag
 from repro.kernels import ops
 from repro.kernels.bulge import (
     bulge_strip_vmem_bytes,
@@ -49,7 +48,7 @@ def test_strip_matches_xla_and_sequential(rng, n):
     Bb = _band(rng, n, B8)
     T = bulge_wavefront_pallas(Bb, B8, group=4, strip=True, interpret=True)
     scale = float(jnp.abs(Bb).max())
-    for oracle in (chase_wavefront_slices, chase_sequential):
+    for oracle in (chase_wavefront, chase_sequential):
         T_ref = oracle(Bb, B8)
         np.testing.assert_allclose(T, T_ref, atol=5e-3 * scale)
         np.testing.assert_allclose(_spectrum(T), _spectrum(T_ref), atol=2e-4 * scale)
@@ -71,7 +70,7 @@ def test_strip_log_reconstructs_the_band(rng, n):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
     # The strip log is slot-compatible with the XLA executor's.
-    _, log_x = chase_wavefront_slices(Bb, B8, return_log=True)
+    _, log_x = chase_wavefront(Bb, B8, return_log=True)
     A = log_x.row0.shape[1]
     np.testing.assert_array_equal(np.asarray(row0)[:, :A], np.asarray(log_x.row0))
     assert np.all(np.asarray(row0)[:, A:] == n)
@@ -122,7 +121,7 @@ def test_dispatch_follows_the_vmem_budget(monkeypatch, rng, fits):
     # Entries drift apart with n under any change of operation order; the
     # spectrum does not.
     scale = float(jnp.abs(Bb).max())
-    want = chase_wavefront_slices(Bb, B8)
+    want = chase_wavefront(Bb, B8)
     np.testing.assert_allclose(_spectrum(T), _spectrum(want), atol=2e-4 * scale)
     if kernel == ops.BULGE_STRIP:
         dense = bulge_wavefront_pallas(Bb, B8, group=4, interpret=True)

@@ -100,7 +100,7 @@ def test_expected_kernels_read_the_plans_record(monkeypatch, n, eigenvectors, ke
     from repro.solver import EvdConfig, plan
 
     monkeypatch.setattr("repro.backend.probe.platform", lambda: "tpu")
-    cfg = EvdConfig(backend="pallas", tridiag="fused")
+    cfg = EvdConfig(backend="pallas")
     assert _chip_smoke().expected_kernels(n, cfg, eigenvectors) == kernels
     pl = plan(n, jnp.float32, cfg)
     assert pl.kernels(eigenvectors) == kernels

@@ -67,22 +67,6 @@ def test_registry_backends_agree_in_dbr(rng):
     np.testing.assert_allclose(B1, B2, atol=5e-5 * float(jnp.abs(B1).max()))
 
 
-def test_custom_syr2k_update_injection(rng):
-    """An explicit syr2k_update callable still bypasses the registry."""
-    calls = {"n": 0}
-
-    def spy_update(C, Y, Z):
-        calls["n"] += 1
-        return C - Z @ Y.T - Y @ Z.T
-
-    n, b, nb = 32, 4, 16
-    A = jnp.asarray(random_symmetric(rng, n))
-    B1 = band_reduce(A, b, nb)
-    B2 = band_reduce(A, b, nb, syr2k_update=spy_update)
-    assert calls["n"] > 0
-    np.testing.assert_allclose(B1, B2, atol=5e-5 * float(jnp.abs(B1).max()))
-
-
 def test_apply_q_left_transpose_roundtrip(rng):
     n, b, nb = 32, 4, 8
     A = jnp.asarray(random_symmetric(rng, n))

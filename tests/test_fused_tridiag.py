@@ -1,32 +1,36 @@
-"""Fused first-stage tridiagonalization: fused-vs-unfused parity and the
-``tridiag`` knob's plumbing.
+"""The first stage: StageSchedule, the fused block step and the chase op.
 
 The parity contract this file pins (DESIGN.md §"Fused first stage"):
 
-* on the **jnp** backend the fused generation is the SAME XLA program as
-  the unfused oracle (band reduction) plus the bitwise-equivalent
-  slice-write chase executor — so BandReflectors, the ChaseLog, and full
-  eigh outputs (eigenvalues AND eigenvectors, full and partial spectrum)
-  must match **bit for bit**;
+* on the **jnp** backend ``band_reduce`` is, bit for bit, a loop of
+  ``_reduce_block`` over the StageSchedule, and ``band_to_tridiag`` is the
+  XLA wavefront executor;
 * on the **pallas** backend the fused kernels accumulate in a different
   order, so parity is entrywise-close + spectrum-tight, the same standard
   ``test_kernels`` applies to the standalone kernels.
 
 Plus: StageSchedule invariants, ragged last-block and prime-n fallback,
-plan-cache keying/no-retrace on the knob, and the kernels.limits env
-overrides.
+and the kernels.limits env overrides.
 """
 import numpy as np
 import pytest
 import jax.numpy as jnp
 
 from repro.backend import registry
-from repro.core import band_reduce, band_to_tridiag, extract_tridiag
-from repro.core.band_reduction import build_stage_schedule
+from repro.core import (
+    band_reduce,
+    band_to_tridiag,
+    chase_sequential,
+    chase_wavefront,
+    extract_tridiag,
+    merge_band_reflectors,
+)
+from repro.core.band_reduction import BandReflectors, _reduce_block, build_stage_schedule
+from repro.core.panel_qr import panel_qr_geqrf
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 from repro.kernels.limits import limit
-from repro.solver import EvdConfig, by_count, plan, trace_count
+from repro.solver import EvdConfig, by_count, plan
 from conftest import random_symmetric
 
 
@@ -56,52 +60,49 @@ def test_stage_schedule_invariants():
 def test_schedule_matches_reflector_blocks(rng):
     n, b, nb = 32, 4, 16
     A = jnp.asarray(random_symmetric(rng, n))
-    for mode in ("fused", "unfused"):
-        _, refl = band_reduce(A, b, nb, return_reflectors=True, mode=mode)
+    for backend in ("pallas", "jnp"):
+        with registry.use_backend(backend):
+            _, refl = band_reduce(A, b, nb, return_reflectors=True)
         assert refl.blocks == build_stage_schedule(n, b, nb).blocks
 
 
 # ------------------------------------------- bit-level parity (jnp backend)
 def test_fused_unfused_bitwise_reflectors_and_log_jnp(rng):
+    # On jnp, band_reduce is a loop of _reduce_block over the schedule, and
+    # band_to_tridiag is the XLA wavefront executor: the same bits.
     n, b, nb = 32, 4, 8
     A = jnp.asarray(random_symmetric(rng, n))
     with registry.use_backend("jnp"):
-        Bf, rf = band_reduce(A, b, nb, return_reflectors=True, merge_ts=True,
-                             mode="fused")
-        Bu, ru = band_reduce(A, b, nb, return_reflectors=True, merge_ts=True,
-                             mode="unfused")
-        _bitwise(Bf, Bu)
-        _bitwise(rf.V, ru.V)
-        _bitwise(rf.T, ru.T)
-        assert rf.blocks == ru.blocks and rf.b == ru.b
-        for tf, tu in zip(rf.Tm, ru.Tm):
-            _bitwise(tf, tu)
+        Bf, rf = band_reduce(A, b, nb, return_reflectors=True, merge_ts=True)
+        Tf, lf = band_to_tridiag(Bf, b, return_log=True)
 
-        Tf, lf = band_to_tridiag(Bf, b, return_log=True, mode="fused")
-        Tu, lu = band_to_tridiag(Bu, b, return_log=True, mode="unfused")
-        _bitwise(Tf, Tu)
-        assert (lf.n, lf.b) == (lu.n, lu.b)
-        _bitwise(lf.vs, lu.vs)
-        _bitwise(lf.taus, lu.taus)
-        _bitwise(lf.row0, lu.row0)
+    sched = build_stage_schedule(n, b, nb)
+    Bu = A
+    Vu = jnp.zeros((n, sched.num_panels * b), A.dtype)
+    Tus = []
+    for e in sched.entries:
+        view, Vbuf, Ts = _reduce_block(
+            Bu[e.ci :, e.ci :], b, e.w, panel_qr_geqrf, kref.trailing_update_ref
+        )
+        Bu = Bu.at[e.ci :, e.ci :].set(view)
+        Vu = Vu.at[e.ci :, e.panel0 * b : (e.panel0 + e.q) * b].set(Vbuf)
+        Tus.append(Ts)
+    ru = merge_band_reflectors(
+        BandReflectors(V=Vu, T=jnp.concatenate(Tus), b=b, blocks=sched.blocks)
+    )
+    _bitwise(Bf, Bu)
+    _bitwise(rf.V, ru.V)
+    _bitwise(rf.T, ru.T)
+    assert rf.blocks == ru.blocks and rf.b == ru.b
+    for tf, tu in zip(rf.Tm, ru.Tm):
+        _bitwise(tf, tu)
 
-
-def test_eigh_bitwise_fused_vs_unfused_jnp(rng):
-    n = 24
-    A = jnp.asarray(random_symmetric(rng, n))
-    cf = EvdConfig(b=4, nb=8, backend="jnp", tridiag="fused")
-    cu = EvdConfig(b=4, nb=8, backend="jnp", tridiag="unfused")
-    wf, Vf = plan(n, jnp.float32, cf)(A)
-    wu, Vu = plan(n, jnp.float32, cu)(A)
-    _bitwise(wf, wu)
-    _bitwise(Vf, Vu)
-    # partial spectrum: the knob only touches the first stage, so the
-    # top-k eigenpairs inherit the same bit-level parity.
-    wfp, Vfp = plan(n, jnp.float32, cf.replace(spectrum=by_count(5)))(A)
-    wup, Vup = plan(n, jnp.float32, cu.replace(spectrum=by_count(5)))(A)
-    assert Vfp.shape == (n, 5)
-    _bitwise(wfp, wup)
-    _bitwise(Vfp, Vup)
+    Tu, lu = chase_wavefront(Bu, b, return_log=True)
+    _bitwise(Tf, Tu)
+    assert (lf.n, lf.b) == (lu.n, lu.b)
+    _bitwise(lf.vs, lu.vs)
+    _bitwise(lf.taus, lu.taus)
+    _bitwise(lf.row0, lu.row0)
 
 
 # --------------------------------------- registry parity (both CI backends)
@@ -120,40 +121,40 @@ def test_registry_fused_panel_update_parity(rng):
 
 
 def test_registry_bulge_wavefront_parity(rng):
+    # The jnp op is the reference for the kernel; chase_sequential, the
+    # paper's serial order, is the reference for the schedule.
     n, b = 24, 4
     A = jnp.asarray(random_symmetric(rng, n))
-    Bband = band_reduce(A, b, 8, mode="unfused")
-    T_ref, l_ref = kref.bulge_wavefront_ref(Bband, b, return_log=True)
-
-    T_jnp, l_jnp = registry.resolve("bulge_wavefront", "jnp")(
+    with registry.use_backend("jnp"):
+        Bband = band_reduce(A, b, 8)
+    T_ref, l_ref = registry.resolve("bulge_wavefront", "jnp")(
         Bband, b, return_log=True
     )
-    _bitwise(T_jnp, T_ref)
-    _bitwise(l_jnp.vs, l_ref.vs)
-    _bitwise(l_jnp.taus, l_ref.taus)
-    _bitwise(l_jnp.row0, l_ref.row0)
+    assert l_ref.vs.shape[-1] == b and (l_ref.n, l_ref.b) == (n, b)
+    scale = max(float(jnp.abs(Bband).max()), 1.0)
+    w_seq = np.linalg.eigvalsh(np.asarray(chase_sequential(Bband, b)))
+    w_ref = np.linalg.eigvalsh(np.asarray(T_ref))
+    np.testing.assert_allclose(w_ref, w_seq, atol=2e-4 * scale)
 
     T_pal = registry.resolve("bulge_wavefront", "pallas")(Bband, b)
     d_ref, e_ref = (np.asarray(x) for x in extract_tridiag(T_ref))
     d_pal, e_pal = (np.asarray(x) for x in extract_tridiag(T_pal))
-    scale = max(np.abs(d_ref).max(), 1.0)
     np.testing.assert_allclose(d_pal, d_ref, atol=5e-3 * scale)
     np.testing.assert_allclose(e_pal, e_ref, atol=5e-3 * scale)
-    w_ref = np.linalg.eigvalsh(np.asarray(T_ref))
     w_pal = np.linalg.eigvalsh(np.asarray(T_pal))
     np.testing.assert_allclose(w_pal, w_ref, atol=2e-4 * scale)
 
 
 # ------------------------------------------------- full pipeline vs scipy
-@pytest.mark.parametrize("mode", ["fused", "unfused"])
-def test_eigh_full_and_partial_vs_numpy(rng, mode):
+@pytest.mark.parametrize("backend", ["pallas", "jnp"])
+def test_eigh_full_and_partial_vs_numpy(rng, backend):
     n = 24
     A0 = random_symmetric(rng, n)
     A = jnp.asarray(A0)
     w_ref, V_ref = np.linalg.eigh(A0)
     scale = np.abs(w_ref).max()
 
-    cfg = EvdConfig(b=4, nb=8, tridiag=mode)
+    cfg = EvdConfig(b=4, nb=8, backend=backend)
     w, V = plan(n, jnp.float32, cfg)(A)
     w, V = np.asarray(w), np.asarray(V)
     np.testing.assert_allclose(w, w_ref, atol=1e-3 * scale)
@@ -178,56 +179,23 @@ def test_ragged_last_block_both_modes(rng):
     A = jnp.asarray(A0)
     w_ref = np.linalg.eigvalsh(A0)
     scale = np.abs(w_ref).max()
-    for mode in ("fused", "unfused"):
-        Bband = band_reduce(A, b, nb, mode=mode)
-        T = band_to_tridiag(Bband, b, mode=mode)
+    for backend in ("pallas", "jnp"):
+        with registry.use_backend(backend):
+            Bband = band_reduce(A, b, nb)
+            T = band_to_tridiag(Bband, b)
         w = np.linalg.eigvalsh(np.asarray(T))
         np.testing.assert_allclose(w, w_ref, atol=1e-3 * scale)
 
 
 def test_prime_n_falls_back_to_direct(rng):
     # 29 is prime: blocking collapses to b=1 and the plan records the
-    # degradation; the tridiag knob must ride along without breaking it.
-    pl = plan(29, jnp.float32, EvdConfig(tridiag="fused"))
+    # degradation.
+    pl = plan(29, jnp.float32, EvdConfig())
     assert pl.fallback_reason is not None
     A0 = random_symmetric(rng, 29)
     w, V = pl(jnp.asarray(A0))
     w_ref = np.linalg.eigvalsh(A0)
     np.testing.assert_allclose(np.asarray(w), w_ref, atol=1e-3 * np.abs(w_ref).max())
-
-
-# ------------------------------------------------------ plan-cache plumbing
-def test_tridiag_knob_resolution_and_cache(monkeypatch):
-    monkeypatch.delenv("REPRO_TRIDIAG", raising=False)
-    cfg = EvdConfig(b=4, nb=8)
-    p_def = plan(28, jnp.float32, cfg)
-    assert p_def.tridiag == "fused"
-    assert "tridiag=fused" in p_def.describe()
-    assert plan(28, jnp.float32, cfg) is p_def  # cache hit
-
-    monkeypatch.setenv("REPRO_TRIDIAG", "unfused")
-    p_env = plan(28, jnp.float32, cfg)
-    assert p_env.tridiag == "unfused"
-    assert p_env is not p_def  # the env knob is part of the cache key
-
-    monkeypatch.setenv("REPRO_TRIDIAG", "bogus")
-    with pytest.raises(ValueError):
-        plan(28, jnp.float32, EvdConfig(b=4, nb=8, backtransform="scan"))
-    with pytest.raises(ValueError):
-        EvdConfig(tridiag="bogus")
-
-
-def test_no_retrace_on_tridiag_knob(rng):
-    A = jnp.asarray(random_symmetric(rng, 28))
-    for mode in ("fused", "unfused"):
-        p = plan(28, jnp.float32, EvdConfig(b=4, nb=8, tridiag=mode))
-        before = trace_count(p)
-        p(A)
-        traced = trace_count(p)
-        p(A)
-        p(A)
-        assert trace_count(p) == traced  # executions after the first don't trace
-        assert traced - before <= 1
 
 
 # ------------------------------------------------------------ limits knobs
@@ -238,23 +206,14 @@ def test_limits_env_override(monkeypatch, rng):
     monkeypatch.setenv("REPRO_FUSED_PANEL_INTERPRET_MAX_M", "0")
     assert limit("FUSED_PANEL_INTERPRET_MAX_M") == 0
     assert not kops.fused_uses_kernel(24, 8, 4)
-    # Over the ceiling the op degrades to the unfused composition — which on
-    # the jnp backend is bit-identical to the reference.
+    # Over the ceiling the op degrades to _reduce_block — which on the jnp
+    # backend is bit-identical to the reference.
     Bv = jnp.asarray(random_symmetric(rng, 24))
     with registry.use_backend("jnp"):
         out = kops.fused_panel_update(Bv, 4, 8)
         ref_out = kref.fused_panel_update_ref(Bv, 4, 8)
     for got, want in zip(out, ref_out):
         _bitwise(got, want)
-
-
-def test_mode_validation_errors(rng):
-    A = jnp.asarray(random_symmetric(rng, 16))
-    with pytest.raises(ValueError):
-        band_reduce(A, 4, 8, mode="sideways")
-    # Injected phases own the composition: fused mode must refuse them.
-    with pytest.raises(ValueError):
-        band_reduce(A, 4, 8, mode="fused", panel_method="householder")
 
 
 # ------------------------------------------------------------- VMEM budget
@@ -270,8 +229,8 @@ def test_tile_bytes_pads_to_vreg_tiles():
     "m,w,fused",
     [
         (1280, 256, True),   # the largest trailing view inside the budget
-        (1536, 256, False),  # over the VMEM budget: unfused composition
-        (256, 248, False),   # w not lane-aligned on the TPU: unfused
+        (1536, 256, False),  # over the VMEM budget: _reduce_block
+        (256, 248, False),   # w not lane-aligned on the TPU: _reduce_block
     ],
 )
 def test_fused_dispatch_counts_vmem_bytes(m, w, fused):

@@ -4,7 +4,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import syr2k, trailing_update, bulge_chase, panel_qr
+from repro.kernels import syr2k, trailing_update, bulge_wavefront, panel_qr
 from repro.kernels.ref import syr2k_ref, trailing_update_ref
 from repro.core import band_reduce, chase_sequential, panel_qr_householder
 from conftest import random_symmetric
@@ -68,7 +68,7 @@ def test_bulge_kernel_vs_sequential(rng, n, b):
 
     A = jnp.asarray(random_symmetric(rng, n))
     B = band_reduce(A, b, min(2 * b, n - b))
-    T1 = bulge_chase(B, b)
+    T1 = bulge_wavefront(B, b)
     T2 = chase_sequential(B, b)
     scale = float(jnp.abs(B).max())
     np.testing.assert_allclose(T1, T2, atol=5e-3 * scale)  # loose entrywise
@@ -87,7 +87,7 @@ def test_bulge_kernel_large_falls_back(monkeypatch, rng):
     monkeypatch.setenv("REPRO_BULGE_INTERPRET_MAX_N", "8")
     n, b = 16, 4
     B = band_reduce(jnp.asarray(random_symmetric(rng, n)), b, b)
-    T = ops.bulge_chase(B, b)  # falls back to XLA wavefront
+    T = ops.bulge_wavefront(B, b)  # falls back to XLA wavefront
     T2 = chase_sequential(B, b)
     np.testing.assert_allclose(T, T2, atol=1e-4 * float(jnp.abs(B).max()))
 

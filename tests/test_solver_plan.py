@@ -17,7 +17,8 @@ from repro.solver import (
     resolve_blocking,
     trace_count,
 )
-from repro.core import eigh, eigvalsh, inverse_pth_root
+from repro.core import eigh, eigh_batched, eigvalsh, inverse_pth_root
+from repro.solver.plan import tridiagonalize
 from conftest import random_symmetric, random_psd
 
 
@@ -32,8 +33,6 @@ def _sym(rng, n=32):
 def test_config_validation():
     with pytest.raises(ValueError):
         EvdConfig(method="qr")
-    with pytest.raises(ValueError):
-        EvdConfig(chase="zigzag")
     with pytest.raises(ValueError):
         EvdConfig(tol=2.0)
     with pytest.raises(ValueError):
@@ -59,6 +58,23 @@ def test_config_hashable_and_frozen():
     assert c1 == c2 and hash(c1) == hash(c2)
     with pytest.raises(Exception):
         c1.b = 8
+
+
+@pytest.mark.parametrize(
+    "field,value", [("tridiag", "fused"), ("chase", "wavefront"), ("backtransform", "blocked")]
+)
+def test_config_has_no_pipeline_options(field, value):
+    # One pipeline (fused first stage, wavefront chase, blocked
+    # back-transform): its oracles are test references, not options.
+    with pytest.raises(TypeError):
+        EvdConfig(**{field: value})
+
+
+@pytest.mark.parametrize("entry", [eigh, eigh_batched, tridiagonalize], ids=lambda f: f.__name__)
+def test_entry_points_reject_chase_kwarg(rng, entry):
+    A = _sym(rng, 16)
+    with pytest.raises(TypeError):
+        entry(A[None] if entry is eigh_batched else A, chase="wavefront")
 
 
 # ---------------------------------------------------------------- plan cache

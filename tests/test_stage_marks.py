@@ -129,7 +129,7 @@ def v5e_programs():
     mp.setattr("repro.backend.probe.platform", lambda: "tpu")
     try:
         n = 512
-        pl = plan(n, jnp.float32, EvdConfig(backend="pallas", tridiag="fused"))
+        pl = plan(n, jnp.float32, EvdConfig(backend="pallas"))
         spec = jax.ShapeDtypeStruct(
             (n, n), jnp.float32, sharding=SingleDeviceSharding(topo.devices[0])
         )
